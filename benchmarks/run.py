@@ -70,18 +70,6 @@ def _setup_host_devices() -> None:
         ).strip()
 
 
-def _setup_jax_cache() -> None:
-    """Persist XLA binaries so repeat benchmark runs skip compilation."""
-    cache = os.environ.get(
-        "REPRO_JAX_CACHE_DIR",
-        os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
-    if not cache:
-        return
-    import jax
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(cache))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
-
 def _print_rows(rows) -> None:
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
@@ -137,7 +125,8 @@ def main(argv=None) -> None:
     fast = args.fast or bool(int(os.environ.get("SIM_FIGS_FAST", "0")))
 
     _setup_host_devices()
-    _setup_jax_cache()
+    from repro.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     bench_sim_path = os.path.join(root, "BENCH_sim.json")
 
@@ -238,11 +227,10 @@ def main(argv=None) -> None:
         # always the FULL request mixes: --fast trims the simulator
         # figure preset, but the serving mixes are cheap even at full
         # size and the PR lane already covers the smoke variant
-        # (serving_translation.py --smoke --pinned).  source="sweep"
-        # makes a broken cost-model derivation FAIL the stage rather
-        # than silently serving the pinned fallback.
-        srows, ssummary = serving_translation.run_serving(
-            fast=False, source="sweep")
+        # (serving_translation.py --smoke --pinned).  The cost model is
+        # derived from the simulator, so a broken derivation FAILS the
+        # stage.
+        srows, ssummary = serving_translation.run_serving(fast=False)
         _print_rows(srows)
         serving_translation.merge_into_bench_json(ssummary,
                                                   bench_sim_path)
@@ -252,11 +240,10 @@ def main(argv=None) -> None:
 
     def st_serving_fleet():
         from benchmarks import serving_fleet
-        # full fleet mix + the mcpt sweep; source="sweep" so a broken
-        # cost-model derivation fails the stage (the PR lane covers the
-        # hermetic smoke variant: serving_fleet.py --smoke --pinned)
-        frows, fsummary = serving_fleet.run_fleet(fast=False,
-                                                  source="sweep")
+        # full fleet mix + the mcpt sweep; a broken cost-model
+        # derivation fails the stage (the PR lane covers the hermetic
+        # smoke variant: serving_fleet.py --smoke --pinned)
+        frows, fsummary = serving_fleet.run_fleet(fast=False)
         _print_rows(frows)
         serving_fleet.merge_into_bench_json(fsummary, bench_sim_path)
         failed = serving_fleet.failed_checks(fsummary)

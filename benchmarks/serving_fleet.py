@@ -127,13 +127,13 @@ def _meter_conserved(eng) -> bool:
     return bool(np.allclose(acc, eng.meter.total, rtol=1e-9, atol=1e-6))
 
 
-def run_fleet(fast: bool = True, pinned: bool = False, seed: int = 0,
-              source: str | None = None) -> Tuple[List[Row], Dict]:
+def run_fleet(fast: bool = True, pinned: bool = False, seed: int = 0
+              ) -> Tuple[List[Row], Dict]:
     from repro.serving.fleet import decode_trace_count
     from repro.sim.cost_model import TranslationCostModel
 
     model = TranslationCostModel.for_machine(
-        source=source or ("pinned" if pinned else "auto"))
+        source="pinned" if pinned else "sweep")
     p = _fleet_params(fast)
     traces0 = decode_trace_count()
 
@@ -302,9 +302,10 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
 
-    from benchmarks.run import _setup_host_devices, _setup_jax_cache
+    from benchmarks.run import _setup_host_devices
+    from repro.util.compile_cache import enable_compile_cache
     _setup_host_devices()
-    _setup_jax_cache()
+    enable_compile_cache()
 
     rows, summary = run_fleet(fast=args.smoke, pinned=args.pinned,
                               seed=args.seed)

@@ -75,12 +75,11 @@ def _engine_factory():
     return cfg, params
 
 
-def run_serving(fast: bool = True, pinned: bool = False, seed: int = 0,
-                source: str | None = None) -> Tuple[List[Row], Dict]:
-    """``source`` overrides the cost-model source; default "pinned"
-    when ``pinned`` else "auto" (memo -> sweep -> pinned fallback).
-    Nightly passes "sweep" so a broken derivation fails the stage
-    instead of silently serving the committed table."""
+def run_serving(fast: bool = True, pinned: bool = False, seed: int = 0
+                ) -> Tuple[List[Row], Dict]:
+    """``pinned`` serves the committed cost table; otherwise the cost
+    model is derived from the simulator (memo when warm), and a broken
+    derivation fails the run."""
     from repro.serving import Request, ServeEngine
     from repro.sim.cost_model import TranslationCostModel
     from repro.sim.simulator import runner_cache_info
@@ -88,7 +87,7 @@ def run_serving(fast: bool = True, pinned: bool = False, seed: int = 0,
     info0 = runner_cache_info()
     t0 = time.perf_counter()
     model = TranslationCostModel.for_machine(
-        source=source or ("pinned" if pinned else "auto"))
+        source="pinned" if pinned else "sweep")
     cost_wall = time.perf_counter() - t0
     cost_compiles = runner_cache_info().misses - info0.misses
 
@@ -190,9 +189,10 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
 
-    from benchmarks.run import _setup_host_devices, _setup_jax_cache
+    from benchmarks.run import _setup_host_devices
+    from repro.util.compile_cache import enable_compile_cache
     _setup_host_devices()
-    _setup_jax_cache()
+    enable_compile_cache()
 
     rows, summary = run_serving(fast=args.smoke, pinned=args.pinned,
                                 seed=args.seed)

@@ -40,7 +40,7 @@ TRACE_LEN = PRESET.trace_len
 #: (workload, machine, cores) -> (SimResult, per-sim wall seconds)
 _CACHE: Dict[Tuple[str, str, int], Tuple[object, float]] = {}
 #: accumulated per-stage wall clock across every bucket run
-_STAGES = {"trace_gen_s": 0.0, "compile_s_est": 0.0, "run_s": 0.0}
+_STAGES = {"trace_gen_s": 0.0, "compile_s": 0.0, "run_s": 0.0}
 
 
 def _machine(machine: str, cores: int):
@@ -64,10 +64,9 @@ def _run_bucket(machine: str, cores: int) -> None:
     results = simulate_batch(_machine(machine, cores), traces,
                              chunk=PRESET.chunk, timings=tm)
     wall = time.perf_counter() - t0
-    # stages are disjoint: run_s already excludes the compile estimate
-    compile_est = tm.get("compile_s_est", 0.0)
-    _STAGES["compile_s_est"] += compile_est
-    _STAGES["run_s"] += tm.get("run_s", wall - compile_est)
+    # stages are disjoint: run_s excludes the ahead-of-time compile
+    _STAGES["compile_s"] += tm["compile_s"]
+    _STAGES["run_s"] += tm["run_s"]
 
     per_sim = wall / len(workloads)
     for w, res in zip(workloads, results):
@@ -245,8 +244,8 @@ ALL_FIGS = [fig4_ptw_latency, fig5_translation_overhead, fig6_core_scaling,
 def perf_summary() -> Dict:
     """Per-mechanism cycles + engine wall-clock for BENCH_sim.json —
     the perf trajectory future PRs compare against.  ``stages`` breaks
-    the fleet wall into trace generation / compile estimate / steady
-    run."""
+    the fleet wall into trace generation / ahead-of-time compile /
+    steady run."""
     mech_cycles: Dict[str, List[float]] = {m: [] for m in DEFAULT_MECHS}
     walls = []
     steps = 0

@@ -194,9 +194,10 @@ def main(argv=None) -> int:
                          "regression baseline")
     args = ap.parse_args(argv)
 
-    from benchmarks.run import _setup_host_devices, _setup_jax_cache
+    from benchmarks.run import _setup_host_devices
+    from repro.util.compile_cache import enable_compile_cache
     _setup_host_devices()
-    _setup_jax_cache()
+    enable_compile_cache()
 
     rows, summary = run_search(args.space, seed=args.seed,
                                use_cache=not args.no_cache)

@@ -286,9 +286,10 @@ def main(argv=None) -> int:
     fast = args.fast or bool(int(os.environ.get("SIM_FIGS_FAST", "0")))
 
     # same env plumbing as run.py: host-device sharding + XLA cache
-    from benchmarks.run import _setup_host_devices, _setup_jax_cache
+    from benchmarks.run import _setup_host_devices
+    from repro.util.compile_cache import enable_compile_cache
     _setup_host_devices()
-    _setup_jax_cache()
+    enable_compile_cache()
 
     presets = [s for s in args.presets.split(",") if s]
     rows, summary = run_sweeps(presets, fast)

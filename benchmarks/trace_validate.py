@@ -142,9 +142,10 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     fast = args.fast or bool(int(os.environ.get("SIM_FIGS_FAST", "0")))
 
-    from benchmarks.run import _setup_host_devices, _setup_jax_cache
+    from benchmarks.run import _setup_host_devices
+    from repro.util.compile_cache import enable_compile_cache
     _setup_host_devices()
-    _setup_jax_cache()
+    enable_compile_cache()
 
     rows, summary = run_validation(fast=fast, cores=args.cores)
     print("name,us_per_call,derived")

@@ -1,9 +1,10 @@
-"""jit'd public wrappers for the Pallas kernels with pure-jnp fallbacks.
+"""jit'd public wrappers for the Pallas kernels and their jnp references.
 
 Dispatch policy:
   * on TPU: compiled Pallas kernels
   * REPRO_KERNEL_IMPL=interpret: Pallas in interpret mode (CPU validation)
-  * otherwise (this CPU container): the jnp reference oracles
+  * otherwise (CPU): the jnp reference oracles
+A failure to initialize the device propagates; it never selects the CPU.
 
 so models/ and serving/ call one API regardless of backend.
 """
@@ -26,11 +27,7 @@ def _impl(override: Optional[str]) -> str:
     env = os.environ.get("REPRO_KERNEL_IMPL")
     if env:
         return env
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover - device init failure
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "ref"
+    return "pallas" if jax.devices()[0].platform == "tpu" else "ref"
 
 
 def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,

@@ -323,7 +323,6 @@ def _paged_attend_shardmap(mesh, q, k_new, v_new, kp, vp, phys_all, lengths,
     phys_all: (B,maxp) SHARD-LOCAL page ids; lengths: (B,).
     Pools shard N->batch axes and hd->model; q/out shard hd->model.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.parallel.sharding import batch_axes
 
@@ -410,8 +409,8 @@ def _paged_attend_shardmap(mesh, q, k_new, v_new, kp, vp, phys_all, lengths,
                     P(dp, None, "model", None), P(dp, None), P(dp))
         out_specs = (P(dp, None, "model", None), P(dp, None, "model", None),
                      P(dp, None, "model", None))
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     elif q_head_mode:
         # query heads shard over "model": each shard sees ALL kv heads of
         # its sequences (pool replicated over model) and h/16 query heads
@@ -423,17 +422,17 @@ def _paged_attend_shardmap(mesh, q, k_new, v_new, kp, vp, phys_all, lengths,
                     P(dp, None), P(dp))
         out_specs = (P(dp, None, "model", None), P(dp, None, None, None),
                      P(dp, None, None, None))
-        fn = shard_map(local_qh, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(local_qh, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(qspec, P(dp, None, md), P(dp, None, md),
                       P(dp, None, None, md), P(dp, None, None, md),
                       P(dp, None), P(dp)),
             out_specs=(P(dp, None, None, md), P(dp, None, None, md),
                        P(dp, None, None, md)),
-            check_rep=False)
+            check_vma=False)
     return fn(q, k_new, v_new, kp, vp, phys_all, lengths)
 
 

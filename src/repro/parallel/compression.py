@@ -59,8 +59,6 @@ def make_dp_int8_allreduce(mesh: Mesh, axis: str = "data"
     payload (widened to int32 for the reduction), and rescales by the max
     of the per-shard scales.
     """
-    from jax.experimental.shard_map import shard_map
-
     def allreduce(g: jnp.ndarray) -> jnp.ndarray:
         def body(x):
             q, s = quantize_int8(x)
@@ -72,6 +70,6 @@ def make_dp_int8_allreduce(mesh: Mesh, axis: str = "data"
             return total.astype(jnp.float32) * s_max / n.astype(jnp.float32)
 
         spec = P(*([None] * g.ndim))
-        return shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec)(g)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec)(g)
 
     return lambda grads: jax.tree.map(allreduce, grads)

@@ -20,7 +20,6 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -71,11 +70,11 @@ def pipeline_run(mesh: Mesh, axis: str, stage_fn: Callable,
         # a psum over the axis broadcasts the finished microbatches
         return jax.lax.psum(outs, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x_micro)
 
 

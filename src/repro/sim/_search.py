@@ -753,15 +753,8 @@ def _main(argv=None) -> int:
     args = ap.parse_args(argv)
     name = args.space or ("quick" if args.quick else "default")
 
-    # same cache plumbing as benchmarks/run.py (src can't import it)
-    import jax
-    cache_dir = os.environ.get("REPRO_JAX_CACHE_DIR",
-                               os.path.join(_ROOT, ".jax_cache"))
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(cache_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
+    from repro.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     result = search(name, seed=args.seed, use_cache=not args.no_cache)
     p = result.provenance

@@ -421,7 +421,7 @@ def run_bucketed(jobs: Sequence[SimJob], *, chunk: int,
             resilience.log_event(
                 "resume", f"bucket {bi} ({shape_str}, {len(idxs)} lanes) "
                           f"restored from {os.path.basename(ckpt_path)}")
-            entry.update(compiles=0, total_s=0.0, compile_s_est=0.0,
+            entry.update(compiles=0, total_s=0.0, compile_s=0.0,
                          resumed=True)
         else:
             before = runner_cache_info().misses
@@ -443,7 +443,7 @@ def run_bucketed(jobs: Sequence[SimJob], *, chunk: int,
             entry.update(
                 compiles=runner_cache_info().misses - before,
                 total_s=round(tm.get("total_s", 0.0), 3),
-                compile_s_est=round(tm.get("compile_s_est", 0.0), 3),
+                compile_s=round(tm.get("compile_s", 0.0), 3),
                 resumed=False)
             if ckpt_path is not None:
                 resilience.write_npz(ckpt_path, _ckpt_pack(outs))

@@ -273,30 +273,22 @@ class TranslationCostModel:
 
     @classmethod
     def for_machine(cls, mach: MachineConfig | None = None, *,
-                    source: str = "auto",
+                    source: str = "sweep",
                     **kw) -> "TranslationCostModel":
         """The serving entry point.  ``source``:
 
         * ``"pinned"`` — the committed table, no simulation (hermetic);
-        * ``"sweep"``  — always derive (memoized to the trace cache);
-        * ``"auto"``   — derive (serving the memo when warm), falling
-          back to the pinned table if the simulator path fails.
+        * ``"sweep"``  — derive from the simulator, serving the trace-
+          cache memo when warm.  A simulator failure raises.
         """
         if source == "pinned":
             return cls.pinned(kw.get("model_cycles_per_token"))
+        if source != "sweep":
+            raise ValueError(f"unknown cost-model source {source!r}")
         if mach is None:
             mach = _FACTORIES[SERVING_COST["machine"]](
                 int(SERVING_COST["cores"]))
-        if source == "sweep":
-            return cls.from_sim(mach, **kw)
-        if source != "auto":
-            raise ValueError(f"unknown cost-model source {source!r}")
-        try:
-            return cls.from_sim(mach, **kw)
-        except Exception as e:                      # noqa: BLE001
-            print(f"# cost model: sweep derivation failed ({e!r}); "
-                  "falling back to the pinned table", file=sys.stderr)
-            return cls.pinned(kw.get("model_cycles_per_token"))
+        return cls.from_sim(mach, **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -8,17 +8,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import pytest
 
+from repro.util.compile_cache import enable_compile_cache
+
 jax.config.update("jax_platform_name", "cpu")
 
 # persist jitted simulator/kernel binaries across test processes: the CI
 # fast lane restores this directory so reruns skip XLA compilation
-_CACHE_DIR = os.environ.get(
-    "REPRO_JAX_CACHE_DIR",
-    os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
-if _CACHE_DIR:
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.abspath(_CACHE_DIR))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+enable_compile_cache()
 
 
 @pytest.fixture(scope="session")

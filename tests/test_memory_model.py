@@ -12,6 +12,7 @@ from repro.core import page_table as PT
 from repro.sim import (MEMORY_MODELS, MemoryModel, apply_param, simulate,
                        simulate_batch, sweep)
 from repro.sim import memory_model as mm
+from repro.sim.simulator import CYCLES_RTOL
 from repro.workloads import generate_trace
 
 try:
@@ -165,14 +166,41 @@ class TestMachineConfig:
 # bounded_linear is bit-exact vs the pre-MemoryModel engine
 # ---------------------------------------------------------------------------
 #: pinned per-mechanism mean cycles of the default bounded engine,
-#: captured on the commit that introduced MemoryModel (the last engine
-#: without it produces these EXACT values) — float64 equality, not rtol
+#: captured on the commit that introduced MemoryModel.  Cycles are f32
+#: sums whose association is the backend's choice, so they are compared
+#: at CYCLES_RTOL (its definition in repro.sim.simulator derives the
+#: bound); the integer-valued counters below are order-free and exact.
 PIN_NDP8_RND = [1833050.75, 1702481.0, 2007893.75, 1330220.75,
                 651822.0625]
 PIN_NDP8_SUMS = (60203748.0, 34130864.0, 31640676.0)
 PIN_CPU4_BC = [390846.4375, 351464.78125, 404215.375, 299769.90625,
                169400.359375]
 PIN_CPU4_SUM = 6462787.5
+#: per-mechanism counter totals over cores (radix, ech, hugepage,
+#: ndpage, ideal)
+PIN_NDP8_COUNTS = dict(
+    walks=[16376, 16376, 16197, 16376, 0],
+    l1tlb_misses=[16383, 16383, 16372, 16383, 0],
+    pte_accesses=[31976, 32752, 30817, 16400, 0],
+    pte_l1_hits=[3104, 0, 2847, 0, 0],
+    pte_mem=[28872, 32752, 27970, 16400, 0],
+    data_l1_misses=[16384] * 5,
+    data_mem=[16384] * 5)
+PIN_CPU4_COUNTS = dict(
+    walks=[2596, 2596, 2135, 2596, 0],
+    l1tlb_misses=[2637, 2637, 2301, 2637, 0],
+    pte_accesses=[4513, 5192, 3064, 2604, 0],
+    pte_l1_hits=[598, 0, 316, 0, 0],
+    pte_mem=[3532, 5192, 2535, 2604, 0],
+    data_l1_misses=[2829, 2828, 2828, 2821, 2821],
+    data_mem=[2821] * 5)
+
+
+def _assert_counts(res, pinned):
+    for name, want in pinned.items():
+        np.testing.assert_array_equal(getattr(res, name).sum(axis=1),
+                                      np.array(want, np.float32),
+                                      err_msg=name)
 
 
 class TestBoundedBitExact:
@@ -180,19 +208,22 @@ class TestBoundedBitExact:
         res = simulate(ndp_machine(8),
                        generate_trace("rnd", 8, length=2048, seed=1234,
                                       preset="smoke"), chunk=512)
-        np.testing.assert_array_equal(res.cycles.mean(axis=1),
-                                      np.array(PIN_NDP8_RND))
-        assert float(res.cycles.sum()) == PIN_NDP8_SUMS[0]
-        assert float(res.trans_cycles.sum()) == PIN_NDP8_SUMS[1]
-        assert float(res.walk_cycles.sum()) == PIN_NDP8_SUMS[2]
+        _assert_counts(res, PIN_NDP8_COUNTS)
+        np.testing.assert_allclose(res.cycles.mean(axis=1),
+                                   PIN_NDP8_RND, rtol=CYCLES_RTOL)
+        np.testing.assert_allclose(
+            [res.cycles.sum(), res.trans_cycles.sum(),
+             res.walk_cycles.sum()], PIN_NDP8_SUMS, rtol=CYCLES_RTOL)
 
     def test_cpu_pinned(self):
         res = simulate(cpu_machine(4),
                        generate_trace("bc", 4, length=1024, seed=7,
                                       preset="smoke"), chunk=256)
-        np.testing.assert_array_equal(res.cycles.mean(axis=1),
-                                      np.array(PIN_CPU4_BC))
-        assert float(res.cycles.sum()) == PIN_CPU4_SUM
+        _assert_counts(res, PIN_CPU4_COUNTS)
+        np.testing.assert_allclose(res.cycles.mean(axis=1),
+                                   PIN_CPU4_BC, rtol=CYCLES_RTOL)
+        np.testing.assert_allclose(res.cycles.sum(), PIN_CPU4_SUM,
+                                   rtol=CYCLES_RTOL)
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """Sharding rules + an 8-device mini dry-run (subprocess: device count must
 be set before jax init, and the main test process keeps 1 device)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -32,6 +33,8 @@ def test_param_rules():
     assert _rule_for(("norm1", "scale"), 1, True) == P()
 
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 MINI_DRYRUN = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -39,8 +42,9 @@ MINI_DRYRUN = textwrap.dedent("""
     import jax
     from repro import config as C
     from repro.launch import dryrun as D
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     out = {}
     import dataclasses
     shape = dataclasses.replace(C.SHAPES["train_4k"], global_batch=8,
@@ -53,8 +57,6 @@ MINI_DRYRUN = textwrap.dedent("""
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         ca = compiled.cost_analysis()
-        if isinstance(ca, list):       # pre-0.4.3x jax returns [dict]
-            ca = ca[0] if ca else {}
         out[arch] = {"temp": mem.temp_size_in_bytes,
                      "flops": ca.get("flops", 0)}
     dshape = dataclasses.replace(C.SHAPES["decode_32k"], global_batch=8,
@@ -73,9 +75,9 @@ def test_mini_dryrun_8_devices():
     proc = subprocess.run(
         [sys.executable, "-c", MINI_DRYRUN % archs],
         capture_output=True, text=True, timeout=560,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        env=dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+                 JAX_PLATFORMS="cpu"),
+        cwd=ROOT)
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
     assert line, proc.stdout
