@@ -19,7 +19,7 @@ gates the machine, not the change.
 
 Refresh the baseline after an intentional perf change with::
 
-    python benchmarks/run.py --fast --sim-only
+    python benchmarks/run.py --fast
     python benchmarks/check_regression.py --update
 
 (or dispatch the ``refresh-baseline`` CI workflow, which runs both on

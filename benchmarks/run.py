@@ -7,8 +7,6 @@ trajectory future PRs compare against).
 Sections (stages):
   * Figs 4-8:   address-translation characterization (NDP vs CPU)
   * Figs 12-14: end-to-end speedups of ECH / HugePage / NDPage / Ideal
-  * kernels:    serving-layer microbenches (translation, paged attention,
-                blockwise attention, engine throughput, simulator speed)
   * --sweeps:   sensitivity sweeps (benchmarks/sim_sweep.py);
                 ``--sweep-presets a,b`` selects a subset
   * --trace-validate: real-vs-synthetic trace comparison
@@ -31,8 +29,7 @@ Sections (stages):
                 (benchmarks/sim_memory.py)
 
 ``--fast`` (or SIM_FIGS_FAST=1) runs the simulator figures on the smoke
-preset — same engine and orderings, CI wall-clock.  ``--sim-only`` skips
-the kernel microbenches.
+preset — same engine and orderings, CI wall-clock.
 
 Every requested stage runs even if an earlier one fails, but ANY stage
 failure (an exception, or a failed ordering/validation check) makes the
@@ -80,8 +77,6 @@ def main(argv=None) -> None:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--fast", action="store_true",
                    help="smoke-preset simulator figures (sub-minute)")
-    p.add_argument("--sim-only", action="store_true",
-                   help="skip the kernel microbenches")
     p.add_argument("--sweeps", action="store_true",
                    help="also run the sensitivity sweeps "
                         "(benchmarks/sim_sweep.py)")
@@ -194,13 +189,6 @@ def main(argv=None) -> None:
         print(f"# wrote {bench_sim_path} "
               f"(figures wall {sim_wall:.1f}s)")
 
-    def st_kernels():
-        from benchmarks import kernel_bench
-        krows = kernel_bench.run_all()
-        _print_rows(krows)
-        rows.extend(krows)
-        write_bench_results()
-
     def st_sweeps():
         # sensitivity sweeps merge their section into BENCH_sim.json
         from benchmarks import sim_sweep
@@ -280,8 +268,6 @@ def main(argv=None) -> None:
             raise RuntimeError(f"memory-model checks FAILED: {failed}")
 
     stage("figures", st_figures)
-    if not args.sim_only:
-        stage("kernels", st_kernels)
     if args.sweeps:
         stage("sweeps", st_sweeps)
     if args.trace_validate:

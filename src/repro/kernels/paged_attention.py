@@ -129,5 +129,8 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
         interpret=interpret,
+        # the kernel's own name in compiled programs and device traces,
+        # whatever jitted function calls it
+        name="paged_attention_pallas_kernel",
     )(block_table, lengths, qk, kp, vp)
     return out.reshape(b, 1, h, d)

@@ -30,7 +30,7 @@ from repro.core import block_table as BT
 from repro.core.kv_page_manager import KVPageManager
 from repro.models import decode_step, init_decode_state
 from repro.serving._scheduler import BatchScheduler, Request
-from repro.util import resilience
+from repro.util import resilience, spans
 
 
 class ServeEngine:
@@ -75,20 +75,26 @@ class ServeEngine:
     def run(self, max_steps: int = 10_000) -> List[Request]:
         finished: List[Request] = []
         for _ in range(max_steps):
-            self.sched.tick()
-            for slot, req in self.sched.admit():
-                # pages for the whole effective prompt were mapped at
-                # admission; teacher-force it from step 0 (for a resumed
-                # request that replays prompt + generated-so-far, so the
-                # KV cache is rebuilt bit-exactly before decode resumes)
-                self._slot_prompt[slot] = req.effective_prompt()
-                self._prompt_pos[slot] = 0
-                self._next_token[slot] = int(self._slot_prompt[slot][0])
-            if not self.sched.running and not self.sched.queue:
-                break
-            if not self.sched.running:
-                continue
-            finished.extend(self._engine_step())
+            # the scheduler tick this iteration takes names its spans
+            step = self.sched.clock + 1
+            with spans.span("repro.serve.step", step=step):
+                with spans.span("repro.serve.schedule", step=step):
+                    self.sched.tick()
+                    for slot, req in self.sched.admit():
+                        # pages for the whole effective prompt were
+                        # mapped at admission; teacher-force it from
+                        # step 0 (for a resumed request that replays
+                        # prompt + generated-so-far, so the KV cache is
+                        # rebuilt bit-exactly before decode resumes)
+                        self._slot_prompt[slot] = req.effective_prompt()
+                        self._prompt_pos[slot] = 0
+                        self._next_token[slot] = int(
+                            self._slot_prompt[slot][0])
+                if not self.sched.running and not self.sched.queue:
+                    break
+                if not self.sched.running:
+                    continue
+                finished.extend(self._engine_step())
         return finished
 
     def throughput(self) -> Dict:
@@ -111,6 +117,7 @@ class ServeEngine:
 
     # -- internals --------------------------------------------------------------
     def _engine_step(self) -> List[Request]:
+        step = self.sched.clock          # this iteration's tick
         # injected mid-decode eviction (the evict_storm chaos plan):
         # preempt the scheduler's victim of choice before the step runs;
         # greedy re-prefill makes the final tokens bit-exact anyway
@@ -119,30 +126,37 @@ class ServeEngine:
             self.sched.preempt(self.sched.pick_victim(), reason="fault")
             if not self.sched.running:
                 return []
-        mode, table, lens = self._build_tables()
-        tokens = jnp.asarray(self._next_token)
+        tc = self.sched.tcache
+        with spans.span("repro.serve.tables", step=step) as sp:
+            hits, misses = tc.hits, tc.misses
+            mode, table, lens = self._build_tables()
+            tokens = jnp.asarray(self._next_token)
+            sp.set(rows=tc.misses - misses, hits=tc.hits - hits)
         state = dict(self.state)
         state["table"] = table
         state["lengths"] = lens
-        logits, new_state = decode_step(self.params, self.cfg, state,
-                                        tokens, kv_mode=mode)
-        self.state = dict(new_state)
-        logits = np.asarray(logits)
+        with spans.span("repro.serve.dispatch", step=step):
+            logits, new_state = decode_step(self.params, self.cfg, state,
+                                            tokens, kv_mode=mode)
+            self.state = dict(new_state)
+        with spans.span("repro.serve.device_wait", step=step):
+            logits = np.asarray(logits)
 
-        produced: Dict[int, int] = {}
-        for sid in self.sched.active_seqs():
-            slot = self.sched.slot_of[sid]
-            self._prompt_pos[slot] += 1
-            pos = self._prompt_pos[slot]
-            stream = self._slot_prompt[slot]
-            if pos < len(stream):
-                # teacher-forced prompt consumption
-                self._next_token[slot] = int(stream[pos])
-            else:
-                nxt = int(np.argmax(logits[slot]))
-                self._next_token[slot] = nxt
-                produced[sid] = nxt
-        return self.sched.record_tokens(produced)
+        with spans.span("repro.serve.sample", step=step):
+            produced: Dict[int, int] = {}
+            for sid in self.sched.active_seqs():
+                slot = self.sched.slot_of[sid]
+                self._prompt_pos[slot] += 1
+                pos = self._prompt_pos[slot]
+                stream = self._slot_prompt[slot]
+                if pos < len(stream):
+                    # teacher-forced prompt consumption
+                    self._next_token[slot] = int(stream[pos])
+                else:
+                    nxt = int(np.argmax(logits[slot]))
+                    self._next_token[slot] = nxt
+                    produced[sid] = nxt
+            return self.sched.record_tokens(produced)
 
     def _build_tables(self):
         mode, rows, _ = self.sched.step_tables()

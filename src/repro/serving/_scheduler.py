@@ -50,7 +50,10 @@ class Request:
     prefix_len: int = 0
     # -- runtime bookkeeping (scheduler-owned) -------------------------------
     retries: int = 0
+    #: scheduler ticks of the request's first submission and first
+    #: admission (-1 until then); their difference is its queue wait
     submit_tick: int = -1
+    admit_tick: int = -1
     not_before: int = 0              # backoff gate for re-admission
     admit_seq: int = -1              # admission order (victim tie-break)
     failed: Optional[str] = None     # "shed" | "deadline" when given up
@@ -62,9 +65,9 @@ class Request:
               prefix_len: int = 0) -> "Request":
         """The public constructor: exactly the caller-owned fields,
         keyword-only.  Runtime bookkeeping (``submit_tick``,
-        ``admit_seq``, ``retries``, ``not_before``, ``failed``) belongs
-        to the scheduler — callers building requests this way can never
-        poke it."""
+        ``admit_tick``, ``admit_seq``, ``retries``, ``not_before``,
+        ``failed``) belongs to the scheduler — callers building requests
+        this way can never poke it."""
         if prefix_id is not None and not (0 <= prefix_len <= len(prompt)):
             raise ValueError(
                 f"prefix_len {prefix_len} outside prompt of {len(prompt)}")
@@ -174,6 +177,8 @@ class BatchScheduler:
             self.running[req.req_id] = req
             self.slot_of[req.req_id] = slot
             req.admit_seq = self.stats["admitted"]
+            if req.admit_tick < 0:
+                req.admit_tick = self.clock
             self.stats["admitted"] += 1
             if req.retries:
                 self.stats["resumed"] += 1

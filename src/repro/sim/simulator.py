@@ -84,7 +84,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
-import time
 from typing import Dict, List, Sequence, Tuple
 
 import jax
@@ -98,6 +97,7 @@ from repro.sim import mechanisms as _mechanisms
 from repro.sim import memory_model as MM
 from repro.sim.mechanisms import (DEFAULT_MECHS, MAX_PTE, specs_for,
                                   tables_for)
+from repro.util import spans
 
 MECHS = DEFAULT_MECHS
 M = len(MECHS)
@@ -403,6 +403,18 @@ def init_state(mach: MachineConfig, m: int = M, batch: int | None = None):
 # ---------------------------------------------------------------------------
 # the model: sequential hit extraction + vectorized timing
 # ---------------------------------------------------------------------------
+#: the ``jax.named_scope`` of each part of a scan step's lookups, carried
+#: into the ``op_name`` metadata of the compiled runner's instructions:
+#: the TLB lookups (L1/L2 DTLB, cache-as-TLB), the page walk (PWC and
+#: the PTE lines' cache and bank-row lookups), the data line's lookups
+SCOPES = ("tlb", "walk", "data")
+
+
+def _line_scope(site: int) -> str:
+    """Scope of one of a step's five line sites: pte0..pte3, then data."""
+    return "walk" if site < MAX_PTE else "data"
+
+
 def _build_model(shape: MachineShape, batched: bool = False):
     """The model, parameterized ONLY by shape: every latency and every
     per-mechanism flag arrives at trace time as an operand (``dp`` data
@@ -466,39 +478,42 @@ def _build_model(shape: MachineShape, batched: bool = False):
         huge = mt["huge"]
         byp = mt["bypass"]
 
-        tlb_key = jnp.where(huge & ~is4k,
-                            (vpn >> HUGE_SHIFT) | (1 << 26), vpn)
-        # direct-segment mechanisms translate in-segment accesses (the
-        # non-fragmented share, ~is4k) via base/limit registers: no TLB
-        # lookup, no walk — only the fragmentation-broken rest enters
-        # the translation machinery below
-        en0 = valid & ~ideal & ~(mt["segment"] & ~is4k)
-        sub["l1tlb"], h_l1tlb = access(sub["l1tlb"], shapes["l1tlb"],
-                                       tlb_key, en0, stamp)
-        en1 = en0 & ~h_l1tlb
-        sub["l2tlb"], h_l2tlb = access(sub["l2tlb"], shapes["l2tlb"],
-                                       tlb_key, en1, stamp + 1)
-        walk = en1 & ~h_l2tlb
-        if has_ctlb:
-            # cache-as-TLB probe after an L2-TLB miss: a hit short-
-            # circuits the walk for cache_tlb mechanisms
-            en_ct = walk & mt["cache_tlb"]
-            sub["ctlb"], h_ctlb = access(sub["ctlb"], shapes["ctlb"],
-                                         tlb_key, en_ct,
-                                         stamp + ctlb_slot)
-            walk = walk & ~h_ctlb
+        with jax.named_scope("tlb"):
+            tlb_key = jnp.where(huge & ~is4k,
+                                (vpn >> HUGE_SHIFT) | (1 << 26), vpn)
+            # direct-segment mechanisms translate in-segment accesses
+            # (the non-fragmented share, ~is4k) via base/limit
+            # registers: no TLB lookup, no walk — only the
+            # fragmentation-broken rest enters the translation
+            # machinery below
+            en0 = valid & ~ideal & ~(mt["segment"] & ~is4k)
+            sub["l1tlb"], h_l1tlb = access(sub["l1tlb"], shapes["l1tlb"],
+                                           tlb_key, en0, stamp)
+            en1 = en0 & ~h_l1tlb
+            sub["l2tlb"], h_l2tlb = access(sub["l2tlb"], shapes["l2tlb"],
+                                           tlb_key, en1, stamp + 1)
+            walk = en1 & ~h_l2tlb
+            if has_ctlb:
+                # cache-as-TLB probe after an L2-TLB miss: a hit short-
+                # circuits the walk for cache_tlb mechanisms
+                en_ct = walk & mt["cache_tlb"]
+                sub["ctlb"], h_ctlb = access(sub["ctlb"], shapes["ctlb"],
+                                             tlb_key, en_ct,
+                                             stamp + ctlb_slot)
+                walk = walk & ~h_ctlb
 
-        # hugepage 4KB-fallback regions walk like radix (4 levels)
-        eff_n = jnp.where(huge & is4k, MAX_PTE, mt["n_pte"])
-        bits = [h_l1tlb, h_l2tlb]
-        pwc_hits = []
-        for lvl in range(MAX_PTE):
-            en = walk & (lvl < eff_n) & mt["pwc_on"][lvl]
-            sub["pwc"], h = access(sub["pwc"], shapes["pwc"],
-                                   pte_lines[lvl], en, stamp + 2 + lvl,
-                                   set_override=lvl)
-            pwc_hits.append(h)
-            bits.append(h)
+        with jax.named_scope("walk"):
+            # hugepage 4KB-fallback regions walk like radix (4 levels)
+            eff_n = jnp.where(huge & is4k, MAX_PTE, mt["n_pte"])
+            bits = [h_l1tlb, h_l2tlb]
+            pwc_hits = []
+            for lvl in range(MAX_PTE):
+                en = walk & (lvl < eff_n) & mt["pwc_on"][lvl]
+                sub["pwc"], h = access(sub["pwc"], shapes["pwc"],
+                                       pte_lines[lvl], en, stamp + 2 + lvl,
+                                       set_override=lvl)
+                pwc_hits.append(h)
+                bits.append(h)
 
         data_line = vpn * 64 + off
         lines = [pte_lines[lvl] for lvl in range(MAX_PTE)] + [data_line]
@@ -509,8 +524,9 @@ def _build_model(shape: MachineShape, batched: bool = False):
             slot = stamp + 2 + MAX_PTE + 5 * h_i
             nxt = []
             for i in range(5):
-                sub[name], h = access(sub[name], shapes[name], lines[i],
-                                      ens[i], slot + i)
+                with jax.named_scope(_line_scope(i)):
+                    sub[name], h = access(sub[name], shapes[name],
+                                          lines[i], ens[i], slot + i)
                 nxt.append(ens[i] & ~h)
                 bits.append(h)
             ens = nxt
@@ -529,12 +545,13 @@ def _build_model(shape: MachineShape, batched: bool = False):
             mem_ens.append(ens[MAX_PTE])
             rows = sub["bank_row"]
             for i in range(5):
-                bk = jax.lax.rem(jax.lax.div(lines[i], lines_per_row),
-                                 n_banks)
-                rw = jax.lax.div(lines[i], lines_per_row * n_banks)
-                bits.append((rows[bk] == rw) & mem_ens[i])
-                rows = rows.at[jnp.where(mem_ens[i], bk, n_banks)].set(
-                    rw, mode="drop")
+                with jax.named_scope(_line_scope(i)):
+                    bk = jax.lax.rem(jax.lax.div(lines[i], lines_per_row),
+                                     n_banks)
+                    rw = jax.lax.div(lines[i], lines_per_row * n_banks)
+                    bits.append((rows[bk] == rw) & mem_ens[i])
+                    rows = rows.at[jnp.where(mem_ens[i], bk, n_banks)].set(
+                        rw, mode="drop")
             sub["bank_row"] = rows
         packed = (jnp.stack(bits)
                   * (1 << jnp.arange(n_bits, dtype=jnp.int32))).sum()
@@ -992,7 +1009,8 @@ def simulate_batch(mach: MachineConfig,
     ``timings``, if given, is filled with wall clock for the benchmark
     drivers: "compile_s" (ahead-of-time ``lower().compile()`` of the
     chunk runner), "run_s" (every chunk, synced, after compilation),
-    "total_s" (their sum), and "chunks".
+    "total_s" (their sum), "chunks", "pack_s" and "transfer_s" (see
+    :func:`simulate_batch_varied`).
     """
     names = DEFAULT_MECHS if mechs is None else tuple(mechs)
     return simulate_batch_varied(
@@ -1027,10 +1045,96 @@ def simulate_batch_varied(jobs: Sequence[SimJob],
     offender otherwise).  Everything value-like varies per lane via the
     mt/dp operand stacks, so e.g. a memory-latency grid or an L1-bypass
     ablation is a single dispatch with zero extra compiles.
+
+    With :mod:`repro.util.spans` on, the call is a ``repro.sim.job``
+    span whose children are ``pack``, ``transfer``, ``compile`` (with
+    ``timings`` only), ``run`` (one ``chunk`` per dispatch, then
+    ``sync``) and ``readback``, each carrying the lane count.
+    ``timings`` is filled from the same clock reads, in seconds:
+    "compile_s", "run_s", "total_s" (their sum), "chunks", and the host's
+    preparation, "pack_s" (numpy packing, huge-page hash, valid mask)
+    and "transfer_s" (upload, state init, chunk slicing).
     """
     b = len(jobs)
     if b == 0:
         return []
+    timed = timings is not None
+    with spans.span("repro.sim.job", lanes=b):
+        p = _prepare(jobs, length, chunk, devices, timings)
+        if timed:
+            # ahead-of-time trace + compile (a no-op when this process
+            # has compiled the runner for these shapes already); the loop
+            # below then dispatches the cached executable
+            with spans.span("repro.sim.compile", clock=True,
+                            lanes=b) as sp:
+                p.runner.lower(p.state, p.chunks[0], p.mt, p.dp).compile()
+            timings["compile_s"] = sp.seconds
+        state = p.state
+        with spans.span("repro.sim.run", clock=timed, lanes=b) as sp:
+            for k, xs_k in enumerate(p.chunks):
+                with spans.span("repro.sim.chunk", lanes=b, index=k):
+                    state = p.runner(state, xs_k, p.mt, p.dp)
+            with spans.span("repro.sim.sync", lanes=b):
+                state = jax.block_until_ready(state)
+        if timed:
+            timings["chunks"] = len(p.chunks)
+            timings["run_s"] = sp.seconds
+            timings["total_s"] = timings["compile_s"] + timings["run_s"]
+
+        with spans.span("repro.sim.readback", lanes=b):
+            cnt = {k: np.asarray(v) for k, v in state["counters"].items()}
+            clock = np.asarray(state["clock"])
+            return [SimResult(
+                mechs=p.jobs[i].mechs,
+                cycles=clock[i],
+                instructions=np.asarray((p.works[i] + 1).sum(axis=1),
+                                        np.float64),
+                trans_cycles=cnt["trans"][i],
+                walk_cycles=cnt["walk_cyc"][i],
+                walks=cnt["walks"][i],
+                l1tlb_misses=cnt["l1tlb_miss"][i],
+                accesses=p.lens[i],
+                pte_accesses=cnt["pte_acc"][i],
+                pte_l1_hits=cnt["pte_l1_hit"][i],
+                pte_mem=cnt["pte_mem"][i],
+                data_l1_misses=cnt["data_l1_miss"][i],
+                data_mem=cnt["data_mem"][i],
+            ) for i in range(b)]
+
+
+def lower_batch(jobs: Sequence[SimJob], length: int | None = None, *,
+                chunk: int = DEFAULT_CHUNK,
+                devices: int | None = None) -> "jax.stages.Lowered":
+    """The chunk runner lowered for ``jobs`` exactly as
+    :func:`simulate_batch_varied` dispatches it (same arguments, shapes
+    and shardings).  Its compiled text names the instructions a device
+    trace of the job shows, with each one's :data:`SCOPES` part in its
+    ``op_name`` metadata."""
+    p = _prepare(jobs, length, chunk, devices)
+    return p.runner.lower(p.state, p.chunks[0], p.mt, p.dp)
+
+
+@dataclasses.dataclass
+class _Prepared:
+    """One bucket's jobs packed, on the device and cut into chunks."""
+    jobs: List[SimJob]
+    runner: object
+    state: Dict
+    chunks: List
+    mt: Dict
+    dp: Dict
+    lens: List[int]
+    works: List[np.ndarray]
+
+
+def _prepare(jobs: Sequence[SimJob], length: int | None, chunk: int,
+             devices: int | None, timings: Dict | None = None
+             ) -> _Prepared:
+    """Checks the bucket, packs its traces on the host (span
+    ``repro.sim.pack``) and moves them and the initial state to the
+    devices (``repro.sim.transfer``), filling ``timings``' "pack_s" and
+    "transfer_s" where it is given."""
+    b = len(jobs)
     jobs = [j if not isinstance(j.trace, str)
             else dataclasses.replace(
                 j, trace=_resolve_trace(j.trace, j.mach.num_cores, length))
@@ -1050,98 +1154,75 @@ def simulate_batch_varied(jobs: Sequence[SimJob],
                 f"job mechs {j.mechs} have different walk functions "
                 "than the bucket's — bucket by walk-fn tuple first")
 
-    vpns, offs, works, lens = [], [], [], []
-    for j in jobs:
-        vpn = j.trace["vpn"][:, :length] if length else j.trace["vpn"]
-        assert vpn.shape[0] == c, (vpn.shape[0], c)
-        vpns.append(vpn)
-        offs.append(j.trace["off"][:, : vpn.shape[1]])
-        works.append(j.trace["work"][:, : vpn.shape[1]])
-        lens.append(vpn.shape[1])
-    t_pad = max(lens) + (-max(lens)) % chunk
+    timed = timings is not None
+    with spans.span("repro.sim.pack", clock=timed, lanes=b) as sp_pack:
+        vpns, offs, works, lens = [], [], [], []
+        for j in jobs:
+            vpn = j.trace["vpn"][:, :length] if length else j.trace["vpn"]
+            assert vpn.shape[0] == c, (vpn.shape[0], c)
+            vpns.append(vpn)
+            offs.append(j.trace["off"][:, : vpn.shape[1]])
+            works.append(j.trace["work"][:, : vpn.shape[1]])
+            lens.append(vpn.shape[1])
+        t_pad = max(lens) + (-max(lens)) % chunk
 
-    ndev = devices
-    if ndev is None:
-        ndev = len(jax.devices()) if os.environ.get("SIM_DEVICES") else 1
-    ndev = max(1, min(ndev, len(jax.devices()), b))
-    bp = b + (-b) % ndev                 # pad B to a device multiple
-    if bp * c < 2:
-        bp = 2      # keep the fused lane axis >= 2 wide: XLA's width-1
-        #             reduce reassociates (see epilogue comment)
+        ndev = devices
+        if ndev is None:
+            ndev = (len(jax.devices()) if os.environ.get("SIM_DEVICES")
+                    else 1)
+        ndev = max(1, min(ndev, len(jax.devices()), b))
+        bp = b + (-b) % ndev                 # pad B to a device multiple
+        if bp * c < 2:
+            bp = 2      # keep the fused lane axis >= 2 wide: XLA's
+            #             width-1 reduce reassociates (see epilogue)
 
-    def pack(arrs, dtype):
-        out = np.zeros((t_pad, bp, c), dtype)
-        for i, a in enumerate(arrs):
-            out[: lens[i], i] = np.ascontiguousarray(a.T)
-        return out
+        def pack(arrs, dtype):
+            out = np.zeros((t_pad, bp, c), dtype)
+            for i, a in enumerate(arrs):
+                out[: lens[i], i] = np.ascontiguousarray(a.T)
+            return out
 
-    # huge-page fragmentation: which 2MB regions fell back to 4KB
-    is4ks = []
-    for j, v in zip(jobs, vpns):
-        frac = FRAC_4K.get(j.mach.num_cores, min(0.93, 0.05 + 0.11 *
-                                                 j.mach.num_cores))
-        is4ks.append((_hash_np(v >> HUGE_SHIFT) % 1000) < int(frac * 1000))
-    valid = np.zeros((t_pad, bp), bool)
-    for i, n in enumerate(lens):
-        valid[:n, i] = True
-    xs = (pack(vpns, np.int32), pack(offs, np.int32),
-          pack(works, np.float32), pack(is4ks, bool), valid)
-    xs = tuple(jnp.asarray(a) for a in xs)
+        # huge-page fragmentation: which 2MB regions fell back to 4KB
+        is4ks = []
+        for j, v in zip(jobs, vpns):
+            frac = FRAC_4K.get(j.mach.num_cores,
+                               min(0.93, 0.05 + 0.11 * j.mach.num_cores))
+            is4ks.append((_hash_np(v >> HUGE_SHIFT) % 1000)
+                         < int(frac * 1000))
+        valid = np.zeros((t_pad, bp), bool)
+        for i, n in enumerate(lens):
+            valid[:n, i] = True
+        xs = (pack(vpns, np.int32), pack(offs, np.int32),
+              pack(works, np.float32), pack(is4ks, bool), valid)
 
-    # per-lane value stacks; pad lanes reuse job 0 (their valid bits are
-    # all False, so their counters are discarded anyway)
-    pad_jobs = list(jobs) + [jobs[0]] * (bp - b)
-    mts = [_mech_arrays(j.mechs) for j in pad_jobs]
-    dps = [_data_params(j.mach) for j in pad_jobs]
-    mt = {k: jnp.asarray(np.stack([t[k] for t in mts])) for k in mts[0]}
-    dp = {k: jnp.asarray(np.stack([d[k] for d in dps])) for k in dps[0]}
+        # per-lane value stacks; pad lanes reuse job 0 (their valid bits
+        # are all False, so their counters are discarded anyway)
+        pad_jobs = list(jobs) + [jobs[0]] * (bp - b)
+        mts = [_mech_arrays(j.mechs) for j in pad_jobs]
+        dps = [_data_params(j.mach) for j in pad_jobs]
+        mt = {k: np.stack([t[k] for t in mts]) for k in mts[0]}
+        dp = {k: np.stack([d[k] for d in dps]) for k in dps[0]}
 
-    state = init_state(jobs[0].mach, m, batch=bp)
-    if ndev > 1:
-        mesh = Mesh(np.asarray(jax.devices()[:ndev]), ("b",))
-        st_sh = NamedSharding(mesh, P("b"))    # state: B leading everywhere
-        xs_sh = NamedSharding(mesh, P(None, "b"))   # xs: (T, B, ...)
-        state = jax.tree.map(lambda a: jax.device_put(a, st_sh), state)
-        xs = tuple(jax.device_put(a, xs_sh) for a in xs)
-        mt = {k: jax.device_put(v, st_sh) for k, v in mt.items()}
-        dp = {k: jax.device_put(v, st_sh) for k, v in dp.items()}
-
-    runner = _chunk_runner(shape, wf, chunk, batch=True)
-    chunks = [jax.tree.map(lambda a: a[i:i + chunk], xs)
-              for i in range(0, t_pad, chunk)]
-    if timings is not None:
-        # ahead-of-time trace + compile (a no-op when this process has
-        # compiled the runner for these shapes already); the loop below
-        # then dispatches the cached executable
-        t0 = time.perf_counter()
-        runner.lower(state, chunks[0], mt, dp).compile()
-        timings["compile_s"] = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for xs_k in chunks:
-        state = runner(state, xs_k, mt, dp)
-    state = jax.block_until_ready(state)
-    if timings is not None:
-        timings["chunks"] = len(chunks)
-        timings["run_s"] = time.perf_counter() - t0
-        timings["total_s"] = timings["compile_s"] + timings["run_s"]
-
-    cnt = {k: np.asarray(v) for k, v in state["counters"].items()}
-    clock = np.asarray(state["clock"])
-    return [SimResult(
-        mechs=jobs[i].mechs,
-        cycles=clock[i],
-        instructions=np.asarray((works[i] + 1).sum(axis=1), np.float64),
-        trans_cycles=cnt["trans"][i],
-        walk_cycles=cnt["walk_cyc"][i],
-        walks=cnt["walks"][i],
-        l1tlb_misses=cnt["l1tlb_miss"][i],
-        accesses=lens[i],
-        pte_accesses=cnt["pte_acc"][i],
-        pte_l1_hits=cnt["pte_l1_hit"][i],
-        pte_mem=cnt["pte_mem"][i],
-        data_l1_misses=cnt["data_l1_miss"][i],
-        data_mem=cnt["data_mem"][i],
-    ) for i in range(b)]
+    with spans.span("repro.sim.transfer", clock=timed, lanes=b) as sp_xfer:
+        xs = tuple(jnp.asarray(a) for a in xs)
+        mt = {k: jnp.asarray(v) for k, v in mt.items()}
+        dp = {k: jnp.asarray(v) for k, v in dp.items()}
+        state = init_state(jobs[0].mach, m, batch=bp)
+        if ndev > 1:
+            mesh = Mesh(np.asarray(jax.devices()[:ndev]), ("b",))
+            st_sh = NamedSharding(mesh, P("b"))   # state: B leading
+            xs_sh = NamedSharding(mesh, P(None, "b"))   # xs: (T, B, ...)
+            state = jax.tree.map(lambda a: jax.device_put(a, st_sh), state)
+            xs = tuple(jax.device_put(a, xs_sh) for a in xs)
+            mt = {k: jax.device_put(v, st_sh) for k, v in mt.items()}
+            dp = {k: jax.device_put(v, st_sh) for k, v in dp.items()}
+        chunks = [jax.tree.map(lambda a: a[i:i + chunk], xs)
+                  for i in range(0, t_pad, chunk)]
+    if timed:
+        timings["pack_s"] = sp_pack.seconds
+        timings["transfer_s"] = sp_xfer.seconds
+    return _Prepared(jobs, _chunk_runner(shape, wf, chunk, batch=True),
+                     state, chunks, mt, dp, lens, works)
 
 
 def _pad_lines(a: jnp.ndarray) -> jnp.ndarray:

@@ -285,7 +285,7 @@ class TestStageTimeout:
         monkeypatch.setattr(sim_figures, "run_all", hang)
         monkeypatch.chdir(tmp_path)           # stray outputs go here
         with pytest.raises(SystemExit) as exc:
-            bench_run.main(["--sim-only", "--stage-timeout", "0.3"])
+            bench_run.main(["--stage-timeout", "0.3"])
         assert exc.value.code != 0
         out = capsys.readouterr().out
         assert "TIMEOUT" in out and "figures" in out
@@ -302,7 +302,7 @@ class TestStageTimeout:
         monkeypatch.setattr(sim_figures, "run_all", boom)
         monkeypatch.chdir(tmp_path)
         with pytest.raises(SystemExit) as exc:
-            bench_run.main(["--sim-only", "--stage-timeout", "30"])
+            bench_run.main(["--stage-timeout", "30"])
         assert exc.value.code != 0
         out = capsys.readouterr().out
         assert "FAIL" in out and "TIMEOUT" not in out

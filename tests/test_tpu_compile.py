@@ -8,6 +8,8 @@ results or speed.
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file."""
+import re
+
 import numpy as np
 import pytest
 
@@ -59,8 +61,13 @@ def test_paged_attention_internlm2_widths(one_chip):
                           cfg.head_dim), jnp.bfloat16)
     table = _spec(one_chip, (b, maxp), jnp.int32)
     lens = _spec(one_chip, (b,), jnp.int32)
-    compiled = paged_attention_pallas.lower(q, kv, kv, table, lens).compile()
-    assert KERNEL_MARKER in compiled.as_text()
+    text = paged_attention_pallas.lower(q, kv, kv, table, lens).compile(
+        ).as_text()
+    assert KERNEL_MARKER in text
+    # the kernel's own name, not its jitted wrapper's, names the custom
+    # call: the op a device trace shows
+    call = re.search(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", text)
+    assert call and call.group(1).startswith("paged_attention_pallas_kernel")
 
 
 def test_flash_attention_s2048(one_chip):
