@@ -1,7 +1,8 @@
 """jit'd public wrappers for the Pallas kernels and their jnp references.
 
 Dispatch policy:
-  * on TPU: compiled Pallas kernels
+  * on TPU: compiled Pallas kernels (paged attention: where a page's
+    (KH, D) lie on whole (8, 128) tiles, else the jnp reference)
   * REPRO_KERNEL_IMPL=interpret: Pallas in interpret mode (CPU validation)
   * otherwise (CPU): the jnp reference oracles
 A failure to initialize the device propagates; it never selects the CPU.
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.paged_attention import pages_tile, paged_attention_pallas
 
 
 def _impl(override: Optional[str]) -> str:
@@ -36,7 +37,7 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     impl: Optional[str] = None) -> jnp.ndarray:
     """Decode attention over paged KV (see kernels/paged_attention.py)."""
     which = _impl(impl)
-    if which == "ref":
+    if which == "ref" or (which == "pallas" and not pages_tile(k_pages)):
         return ref.paged_attention_ref(q, k_pages, v_pages, block_table,
                                        valid_lens, window=window)
     return paged_attention_pallas(q, k_pages, v_pages, block_table,

@@ -132,6 +132,14 @@ class ServeEngine:
             mode, table, lens = self._build_tables()
             tokens = jnp.asarray(self._next_token)
             sp.set(rows=tc.misses - misses, hits=tc.hits - hits)
+            # what each layer's paged-attention call fetches (the pages
+            # of every slot's live range, the new token's included) out
+            # of what a dense walk of the table would
+            spans.count("repro.serve.kv_pages_live",
+                        int((-(-(lens + 1) // self.page_size)).sum()))
+            spans.count("repro.serve.kv_pages_table",
+                        self.max_batch * self.kvm.max_pages)
+            lens = jnp.asarray(lens)
         state = dict(self.state)
         state["table"] = table
         state["lengths"] = lens
@@ -174,7 +182,7 @@ class ServeEngine:
         if mode == BT.RADIX:
             table = BT.radix_from_flat(
                 table, leaf_size=min(16, self.kvm.max_pages))
-        return mode, table, jnp.asarray(lens)
+        return mode, table, lens
 
 
 def greedy_reference(cfg, params, prompt: np.ndarray, new_tokens: int,

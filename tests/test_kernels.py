@@ -1,4 +1,6 @@
 """Per-kernel allclose sweeps vs the pure-jnp oracles (interpret mode)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,7 +8,7 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.paged_attention import block_pages, paged_attention_pallas
 
 
 def _tol(dtype):
@@ -14,7 +16,12 @@ def _tol(dtype):
         rtol=2e-5, atol=2e-5)
 
 
-def _paged_inputs(b, h, kh, d, page, maxp, dtype, seed=0, frac=0.7):
+def _paged_inputs(b, h, kh, d, page, maxp, dtype, seed=0, lens=None,
+                  hole=False):
+    """Random q and pools, and a table mapping each sequence's live pages
+    to distinct physical pages (-1 past them).  ``lens`` fixes the
+    lengths; ``hole`` unmaps the second page of every sequence that has
+    three or more."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     n = b * maxp + 2
     q = jax.random.normal(ks[0], (b, 1, h, d), dtype)
@@ -22,26 +29,40 @@ def _paged_inputs(b, h, kh, d, page, maxp, dtype, seed=0, frac=0.7):
     vp = jax.random.normal(ks[2], (n, page, kh, d), dtype)
     rng = np.random.default_rng(seed)
     tab = np.full((b, maxp), -1, np.int32)
-    lens = np.zeros((b,), np.int32)
     perm = rng.permutation(n)
+    if lens is None:
+        lens = [rng.integers(1, maxp * page + 1) for _ in range(b)]
+    lens = np.asarray(lens, np.int32)
     k = 0
     for i in range(b):
-        lens[i] = rng.integers(1, maxp * page + 1)
         used = -(-int(lens[i]) // page)
         tab[i, :used] = perm[k:k + used]
         k += used
+        if hole and used >= 3:
+            tab[i, 1] = -1
     return q, kp, vp, jnp.asarray(tab), jnp.asarray(lens)
+
+
+#: tokens a kernel block holds at 8-token pages, 4 KV heads, MAXP 64
+BK = block_pages(8, 4, 64) * 8
+assert BK < 512
 
 
 class TestPagedAttentionKernel:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("b,h,kh,d,page,maxp", [
-        (2, 8, 2, 64, 16, 8),       # GQA
-        (1, 4, 1, 128, 32, 4),      # MQA
-        (3, 4, 4, 32, 8, 16),       # MHA
+    @pytest.mark.parametrize("b,h,kh,d,page,maxp,lens,hole", [
+        (2, 8, 2, 64, 16, 8, None, False),       # GQA
+        (1, 4, 1, 128, 32, 4, None, False),      # MQA
+        (3, 4, 4, 32, 8, 16, None, False),       # MHA
+        # 1 token, one page, one block, one past a block, MAXP * page
+        (5, 8, 4, 32, 8, 64, (1, 8, BK, BK + 1, 512), False),
+        (3, 8, 2, 64, 16, 8, (40, 100, 128), True),   # -1 inside the range
+        (3, 16, 8, 128, 16, 16, None, True),     # internlm2 heads, 2 blocks
     ])
-    def test_matches_oracle(self, b, h, kh, d, page, maxp, dtype):
-        q, kp, vp, tab, lens = _paged_inputs(b, h, kh, d, page, maxp, dtype)
+    def test_matches_oracle(self, b, h, kh, d, page, maxp, lens, hole,
+                            dtype):
+        q, kp, vp, tab, lens = _paged_inputs(b, h, kh, d, page, maxp, dtype,
+                                             lens=lens, hole=hole)
         want = ref.paged_attention_ref(q, kp, vp, tab, lens)
         got = paged_attention_pallas(q, kp, vp, tab, lens, interpret=True)
         np.testing.assert_allclose(
@@ -58,11 +79,44 @@ class TestPagedAttentionKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
+    @pytest.mark.parametrize("window", [0, 24])
+    def test_dead_pages_are_never_read(self, window):
+        """Every pool page outside the live ranges is NaN: page 0, which
+        an unmapped entry would clamp to, pages mapped past a sequence's
+        length, and (with a window) pages wholly before it.  The kernel
+        must fetch none of them."""
+        b, h, kh, d, page, maxp = 3, 8, 2, 64, 8, 32
+        q, kp, vp, tab, lens = _paged_inputs(
+            b, h, kh, d, page, maxp, jnp.float32, seed=5,
+            lens=(3, 70, 200), hole=True)
+        tab = np.array(tab)
+        n = kp.shape[0]
+        # page 0 is never live; stale pages are mapped past the length
+        # of the first two sequences
+        free = [p for p in range(1, n) if p not in tab]
+        tab[tab == 0] = free.pop()
+        tab[0, 1:4] = free[:3]
+        tab[1, 9:11] = free[3:5]
+        pos = np.arange(maxp)[None, :] * page
+        live = ((pos < np.asarray(lens)[:, None])
+                & (pos + page > np.asarray(lens)[:, None] - window
+                   if window else True) & (tab >= 0))
+        dead = np.setdiff1d(np.arange(n), tab[live])
+        assert 0 in dead and len(dead) > n // 2
+        kp_nan = kp.at[dead].set(jnp.nan)
+        vp_nan = vp.at[dead].set(jnp.nan)
+        tab = jnp.asarray(tab)
+        got = paged_attention_pallas(q, kp_nan, vp_nan, tab, lens,
+                                     window=window, interpret=True)
+        assert np.isfinite(np.asarray(got)).all()
+        want = ref.paged_attention_ref(q, kp, vp, tab, lens, window=window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
     def test_physical_placement_invariance(self):
         """NDPage core invariant: output independent of WHERE pages live."""
         q, kp, vp, tab, lens = _paged_inputs(2, 4, 2, 64, 8, 4, jnp.float32,
                                              seed=7)
-        out1 = ref.paged_attention_ref(q, kp, vp, tab, lens)
         # permute physical pages and rewrite the table accordingly
         n = kp.shape[0]
         perm = np.random.default_rng(1).permutation(n)
@@ -70,9 +124,12 @@ class TestPagedAttentionKernel:
         kp2 = kp[perm]
         vp2 = vp[perm]
         tab2 = jnp.where(tab >= 0, jnp.asarray(inv)[jnp.maximum(tab, 0)], -1)
-        out2 = ref.paged_attention_ref(q, kp2, vp2, tab2, lens)
-        np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
-                                   rtol=1e-6)
+        for attend in (ref.paged_attention_ref, functools.partial(
+                paged_attention_pallas, interpret=True)):
+            out1 = attend(q, kp, vp, tab, lens)
+            out2 = attend(q, kp2, vp2, tab2, lens)
+            np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
+                                       rtol=1e-6)
 
 
 class TestFlashAttentionKernel:
@@ -113,6 +170,16 @@ class TestOpsDispatch:
         a = ops.paged_attention(q, kp, vp, tab, lens)
         b = ref.paged_attention_ref(q, kp, vp, tab, lens)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b))
+
+    def test_untiled_pages_take_the_reference(self):
+        """Compiled, the kernel DMAs whole pages, which needs (KH, D) on
+        whole (8, 128) tiles; other widths take the jnp reference."""
+        q, kp, vp, tab, lens = _paged_inputs(2, 4, 2, 32, 8, 2, jnp.float32)
+        a = ops.paged_attention(q, kp, vp, tab, lens, impl="pallas")
+        b = ref.paged_attention_ref(q, kp, vp, tab, lens)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b))
+        with pytest.raises(ValueError, match="tiles"):
+            paged_attention_pallas(q, kp, vp, tab, lens)
 
     def test_blockwise_jnp_matches_flash_ref(self):
         """models.attention.blockwise_attention is itself oracle-consistent."""

@@ -132,6 +132,10 @@ def test_engine_step_spans_and_tokens_on_and_off():
     assert all({"rows", "hits"} <= set(t.attrs) for t in tables)
     assert sum(t.attrs["rows"] for t in tables) >= 3    # one per request
     assert sum(t.attrs["hits"] for t in tables) > 0
+    # every step fetches at least a page a slot (2 slots of 6 pages)
+    live = got.counters["repro.serve.kv_pages_live"]
+    assert 2 * len(tables) <= live < got.counters["repro.serve.kv_pages_table"]
+    assert got.counters["repro.serve.kv_pages_table"] == 12 * len(tables)
 
 
 def test_scheduler_records_queue_wait_ticks():

@@ -68,6 +68,43 @@ def test_paged_attention_internlm2_widths(one_chip):
     # call: the op a device trace shows
     call = re.search(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", text)
     assert call and call.group(1).startswith("paged_attention_pallas_kernel")
+    # the kernel reads the pools in place: no instruction outside it
+    # makes a pool-sized array, in the pools' layout or transposed
+    pool = re.compile(r"bf16\[(%d,%d,%d,%d|%d,%d,%d,%d)\]" % (
+        kv.shape + (kv.shape[2], kv.shape[0], page, cfg.head_dim)))
+    made = [line for line in text.splitlines()
+            if (m := re.match(r"\s*(ROOT )?%\S+ = (\S+) ([\w-]+)\(", line))
+            and pool.match(m.group(2)) and m.group(3) != "parameter"]
+    assert not made, made
+
+
+def test_decode_step_reads_the_kernel_through_a_bitcast(one_chip,
+                                                        monkeypatch):
+    """In the served decode step (internlm2-1.8b widths, two layers, the
+    one-chip deployment), the kernel's output feeds the step through a
+    bitcast: no op that runs on the device names the kernel among its
+    operands, so a device trace holds one op per kernel call that
+    carries its name."""
+    import dataclasses
+    import repro.kernels.ops as ops
+    from repro.core import block_table as BT
+    from repro.models import decode_step, init_decode_state, init_params
+    monkeypatch.setattr(ops, "_impl", lambda override: "pallas")
+    cfg = dataclasses.replace(C.get_arch("internlm2-1.8b"), num_layers=2)
+    on_chip = lambda tree: jax.tree.map(                     # noqa: E731
+        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    state = on_chip(jax.eval_shape(lambda: init_decode_state(
+        cfg, 8, 2048, BT.FLAT, 16, num_pages=8 * 128 + 8)))
+    tokens = _spec(one_chip, (8,), jnp.int32)
+    text = decode_step.lower(params, cfg, state, tokens, BT.FLAT).compile(
+        ).as_text()
+    kernel = re.findall(r"%(paged_attention_pallas_kernel[\w.]*) = ", text)
+    assert len(kernel) == 1, kernel
+    uses = [line for line in text.splitlines()
+            if re.search(r"%" + re.escape(kernel[0]) + r"[,)]", line)]
+    assert uses and all(" bitcast(" in line for line in uses), uses
 
 
 def test_flash_attention_s2048(one_chip):
