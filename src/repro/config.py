@@ -29,13 +29,31 @@ MOE_FF = "moe"           # routed mixture-of-experts FFN
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int               # routed experts: the router's width
     num_experts_per_tok: int
     num_shared_experts: int = 0
     expert_d_ff: int = 0           # intermediate size of each routed expert
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # group-limited greedy routing (DeepSeek-V2): only the experts of the
+    # ``topk_group`` groups (of ``n_group``) with the best single expert
+    # score compete for the top k; 1 group is plain top-k
+    n_group: int = 1
+    topk_group: int = 1
+    # top-k weights: renormalised to sum 1, or else scaled by this factor
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # expert parallelism: this chip holds the routed experts
+    # [first_expert, first_expert + experts_held) and computes only their
+    # part of the layer (0 = all of them)
+    first_expert: int = 0
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -45,6 +63,17 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -99,6 +128,7 @@ class ArchConfig:
 
     # misc
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None   # latent attention's rope
     rms_norm_eps: float = 1e-5
     tie_embeddings: bool = False
     gated_ffn: bool = True          # SwiGLU-style if True, GELU MLP otherwise
@@ -181,7 +211,7 @@ class ArchConfig:
         d = self.d_model
         if kind == MOE_FF:
             assert self.moe is not None
-            total = self.moe.num_experts * self._expert_params()
+            total = self.moe.held * self._expert_params()
             total += self.moe.num_shared_experts * self._expert_params()
             total += d * self.moe.num_experts  # router
             return total

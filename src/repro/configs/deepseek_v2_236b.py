@@ -1,12 +1,16 @@
-"""deepseek-v2-236b [arXiv:2405.04434].
+"""deepseek-v2-236b [arXiv:2405.04434], as its published config.json has it.
 
-60L d_model=5120 128H (MLA kv_lora=512) vocab=102400.
-MoE: 160 routed experts top-6 + 2 shared experts, expert_d_ff=1536.
+60L d_model=5120 128H (MLA kv_lora=512) vocab=102400, rms_norm_eps 1e-6.
+MoE: 160 routed experts top-6 + 2 shared experts, expert_d_ff=1536;
+group-limited greedy routing (8 groups, the best 3 kept), softmax scores,
+top-6 weights not renormalised but scaled by 16.
 Layer 0 uses a dense FFN (d_ff=12288), layers 1..59 use MoE (per the paper).
-MLA: q_lora=1536, kv_lora=512, qk_nope=128, qk_rope=64, v_head=128.
+MLA: q_lora=1536, kv_lora=512, qk_nope=128, qk_rope=64, v_head=128; the
+rope part uses YaRN (factor 40 over 4096 original positions, mscale and
+mscale_all_dim 0.707).
 """
 from repro.config import (ATTN_MLA, DENSE_FF, MOE_FF, ArchConfig, MLAConfig,
-                          MoEConfig, register)
+                          MoEConfig, YarnConfig, register)
 
 # layer 0 dense FFN (prefix, unscanned); layers 1..59 MoE (scanned)
 _PREFIX = ((ATTN_MLA, DENSE_FF),)
@@ -25,7 +29,14 @@ CONFIG = register(ArchConfig(
     layer_pattern=_PATTERN,
     prefix_pattern=_PREFIX,
     moe=MoEConfig(num_experts=160, num_experts_per_tok=6,
-                  num_shared_experts=2, expert_d_ff=1536),
+                  num_shared_experts=2, expert_d_ff=1536,
+                  n_group=8, topk_group=3, norm_topk_prob=False,
+                  routed_scaling_factor=16.0),
     mla=MLAConfig(kv_lora_rank=512, q_lora_rank=1536,
                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
+    rope_theta=10_000.0,
+    rope_scaling=YarnConfig(factor=40.0, original_max_position_embeddings=4096,
+                            beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                            mscale_all_dim=0.707),
+    rms_norm_eps=1e-6,
 ))

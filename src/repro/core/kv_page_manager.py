@@ -204,14 +204,30 @@ class KVPageManager:
 # ---------------------------------------------------------------------------
 def append_kv(kp: jnp.ndarray, vp: jnp.ndarray, k_new: jnp.ndarray,
               v_new: jnp.ndarray, phys_page: jnp.ndarray,
-              slot: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              slot: jnp.ndarray, layer=None
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Scatter one new token's K/V into the pools.
 
-    kp/vp: (N, page, K, H); k_new/v_new: (B, K, H); phys_page, slot: (B,).
+    kp/vp: (N, page, K, H), or (L, N, page, K, H) stacked over layers with
+    ``layer`` the one to write; k_new/v_new: (B, K, H); phys_page, slot:
+    (B,).
     """
-    kp = kp.at[phys_page, slot].set(k_new)
-    vp = vp.at[phys_page, slot].set(v_new)
+    at = (phys_page, slot) if layer is None else (layer, phys_page, slot)
+    kp = kp.at[at].set(k_new)
+    vp = vp.at[at].set(v_new)
     return kp, vp
+
+
+def append_rows(pool: jnp.ndarray, rows: jnp.ndarray, phys_page: jnp.ndarray,
+                slot: jnp.ndarray, layer=None) -> jnp.ndarray:
+    """Scatter one new token's row into a single pool (latent attention
+    keeps one row a token for every head).
+
+    pool: (N, page, R), or (L, N, page, R) with ``layer`` the one to
+    write; rows: (B, R); phys_page, slot: (B,).
+    """
+    at = (phys_page, slot) if layer is None else (layer, phys_page, slot)
+    return pool.at[at].set(rows)
 
 
 def gather_kv(kp: jnp.ndarray, vp: jnp.ndarray, phys: jnp.ndarray

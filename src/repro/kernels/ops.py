@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import pages_tile, paged_attention_pallas
+from repro.kernels.paged_latent_attention import \
+    paged_latent_attention_pallas
 
 
 def _impl(override: Optional[str]) -> str:
@@ -43,6 +45,23 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     return paged_attention_pallas(q, k_pages, v_pages, block_table,
                                   valid_lens, window=window,
                                   interpret=(which == "interpret"))
+
+
+def paged_latent_attention(q: jnp.ndarray, pool: jnp.ndarray,
+                           block_table: jnp.ndarray, valid_lens: jnp.ndarray,
+                           layer=0, *, kv_lora: int, scale: float,
+                           impl: Optional[str] = None) -> jnp.ndarray:
+    """Absorbed MLA decode over a paged latent pool, (N, page, R) or
+    stacked (L, N, page, R) and read at ``layer`` (see
+    kernels/paged_latent_attention.py)."""
+    which = _impl(impl)
+    if which == "ref":
+        return ref.paged_latent_attention_ref(q, pool, block_table,
+                                              valid_lens, layer,
+                                              kv_lora=kv_lora, scale=scale)
+    return paged_latent_attention_pallas(q, pool, block_table, valid_lens,
+                                         layer, kv_lora=kv_lora, scale=scale,
+                                         interpret=(which == "interpret"))
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -57,6 +57,36 @@ def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     return out.reshape(b, s1, h, d).astype(q.dtype)
 
 
+def paged_latent_attention_ref(q: jnp.ndarray, pool: jnp.ndarray,
+                               block_table: jnp.ndarray,
+                               valid_lens: jnp.ndarray, layer=0, *,
+                               kv_lora: int, scale: float) -> jnp.ndarray:
+    """Absorbed latent-attention decode over a paged latent pool.
+
+    q: (B, H, R) queries against whole rows; pool: (N, page, R) rows of
+    [c_kv (kv_lora) | rotary key | padding], or (L, N, page, R) of which
+    ``layer`` is read; block_table: (B, max_pages) (-1 = unmapped);
+    valid_lens: (B,) attendable tokens.  Each head scores every row and
+    averages the rows' first ``kv_lora`` lanes.  Returns (B, H, kv_lora).
+    """
+    if pool.ndim == 4:
+        pool = pool[layer]
+    b, h, r = q.shape
+    page = pool.shape[1]
+    maxp = block_table.shape[1]
+    rows = pool[jnp.maximum(block_table, 0)].reshape(b, maxp * page, r)
+    s = jnp.einsum("bhr,btr->bht", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    kpos = jnp.arange(maxp * page)
+    mask = kpos[None, :] < valid_lens[:, None]
+    mask &= (block_table >= 0).repeat(page, axis=1)
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bht,btc->bhc", w, rows[..., :kv_lora],
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
 def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         *, causal: bool = True, window: int = 0
                         ) -> jnp.ndarray:

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 
 from repro.core import block_table as BT
 from repro.core import kv_page_manager as KVM
-from repro.models.layers import apply_rope, dense_init, rmsnorm, rmsnorm_init
+from repro.models.layers import (apply_rope, dense_init, rmsnorm,
+                                 rmsnorm_init, yarn_mscale)
 from repro.parallel.context import BATCH, constrain_act
 
 Params = Dict[str, Any]
@@ -264,12 +265,15 @@ def attn_decode_dense(params: Params, x: jnp.ndarray, cache_k, cache_v,
 # ---------------------------------------------------------------------------
 def attn_decode_paged(params: Params, x: jnp.ndarray, kp, vp, table,
                       lengths: jnp.ndarray, cfg, *, window: int = 0,
-                      mode: str = BT.FLAT):
+                      mode: str = BT.FLAT, layer=None):
     """One-token decode against paged KV pools.
 
-    kp/vp: (N_pages, page, K, D) pools; ``table`` is a flat (B, max_pages)
-    map (NDPage) or a RadixTable (2-level baseline).  The table translate is
-    the address-translation step; flat mode does ONE indirection, radix TWO.
+    kp/vp: (N_pages, page, K, D) pools, or the pools of a stack's scanned
+    layers (L, N_pages, page, K, D) with ``layer`` this layer's index: the
+    new token is appended in place and the kernel reads the layer's pools
+    sliced out.  ``table`` is a flat (B, max_pages) map (NDPage) or a
+    RadixTable (2-level baseline).  The table translate is the
+    address-translation step; flat mode does ONE indirection, radix TWO.
     Returns (y, kp, vp).
 
     With a mesh installed (repro.parallel.context) the data path runs under
@@ -283,7 +287,7 @@ def attn_decode_paged(params: Params, x: jnp.ndarray, kp, vp, table,
 
     b = x.shape[0]
     h, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    page = kp.shape[1]
+    page = kp.shape[-3]
     q = (x @ params["wq"]).reshape(b, 1, h, hd)
     k = (x @ params["wk"]).reshape(b, 1, kh, hd)
     v = (x @ params["wv"]).reshape(b, 1, kh, hd)
@@ -296,17 +300,22 @@ def attn_decode_paged(params: Params, x: jnp.ndarray, kp, vp, table,
 
     mesh = current_mesh()
     if mesh is not None:
-        out, kp, vp = _paged_attend_shardmap(
-            mesh, q, k[:, 0], v[:, 0], kp, vp, phys_all, lengths,
+        kl, vl = (kp, vp) if layer is None else (kp[layer], vp[layer])
+        out, kl, vl = _paged_attend_shardmap(
+            mesh, q, k[:, 0], v[:, 0], kl, vl, phys_all, lengths,
             window=window, cfg=cfg)
+        kp, vp = ((kl, vl) if layer is None else
+                  (kp.at[layer].set(kl), vp.at[layer].set(vl)))
     else:
         bidx = jnp.arange(b)
         logical = lengths // page
         phys_new = phys_all[bidx, logical]
         kp, vp = KVM.append_kv(kp, vp, k[:, 0], v[:, 0],
-                               jnp.maximum(phys_new, 0), lengths % page)
+                               jnp.maximum(phys_new, 0), lengths % page,
+                               layer=layer)
         from repro.kernels import ops as KOPS
-        out = KOPS.paged_attention(q, kp, vp, phys_all, lengths + 1,
+        kl, vl = (kp, vp) if layer is None else (kp[layer], vp[layer])
+        out = KOPS.paged_attention(q, kl, vl, phys_all, lengths + 1,
                                    window=window)
     # contract (heads, head_dim) against wo without flattening so an
     # hd-sharded attention output psums once into (B, 1, D)
@@ -439,6 +448,24 @@ def _paged_attend_shardmap(mesh, q, k_new, v_new, kp, vp, phys_all, lengths,
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek multi-head latent attention)
 # ---------------------------------------------------------------------------
+def latent_row(cfg) -> int:
+    """Lanes of one cached token in a latent page: ``c_kv``, then the
+    rotary key, zero-padded to whole 128-lane tiles (the TPU's HBM tiling
+    pads a row to them anyway, and the kernel DMAs whole tiles)."""
+    m = cfg.mla
+    return -(-(m.kv_lora_rank + m.qk_rope_head_dim) // 128) * 128
+
+
+def mla_softmax_scale(cfg) -> float:
+    """1/sqrt(qk head dim), times YaRN's mscale(mscale_all_dim)^2."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_qkv_full(params, x, positions, cfg):
     m = cfg.mla
     b, s, _ = x.shape
@@ -447,12 +474,13 @@ def _mla_qkv_full(params, x, positions, cfg):
     q = (cq @ params["w_uq"]).reshape(
         b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     dkv = x @ params["w_dkv"]
     c_kv, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(params["kv_norm"], c_kv, cfg.rms_norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)
     return q_nope, q_rope, c_kv, k_rope[:, :, 0, :]
 
 
@@ -473,19 +501,13 @@ def mla_apply(params: Params, x: jnp.ndarray, positions, cfg,
     k_full = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (b, s, h, m.qk_rope_head_dim))], axis=-1)
-    # pad v so self_attention's (K==V dim) contract works uniformly
-    scale_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
-    out = self_attention(q_full * math.sqrt(out_scale(scale_dim, m)),
+    # self_attention scales by 1/sqrt(qk dim); YaRN's mscale^2 rides on q
+    qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
+    out = self_attention(q_full * (mla_softmax_scale(cfg) * math.sqrt(qk_dim)),
                          k_full, v_padded(v, k_full.shape[-1]),
                          causal=causal)
     out = out[..., : m.v_head_dim]
     return out.reshape(b, s, h * m.v_head_dim) @ params["wo"]
-
-
-def out_scale(scale_dim: int, m) -> float:
-    # self_attention scales by 1/sqrt(d) with d = padded dim; correct to
-    # 1/sqrt(qk_dim)
-    return 1.0
 
 
 def v_padded(v, dim):
@@ -495,51 +517,87 @@ def v_padded(v, dim):
     return jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
 
 
+def _absorbed_q(params, q_nope, cfg):
+    """q_nope (B, 1, H, nope) with W_uk absorbed: (B, H, kv_lora) f32."""
+    m = cfg.mla
+    w_uk = params["w_uk"].reshape(m.kv_lora_rank, cfg.num_heads,
+                                  m.qk_nope_head_dim)
+    return jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_uk,
+                      preferred_element_type=jnp.float32)
+
+
+def _mla_out(params, ctx, x, cfg):
+    """Latent context (B, H, kv_lora) through W_uv and wo: (B, 1, D)."""
+    m = cfg.mla
+    b, h = ctx.shape[:2]
+    w_uv = params["w_uv"].reshape(m.kv_lora_rank, h, m.v_head_dim)
+    out = jnp.einsum("bhc,chv->bhv", ctx, w_uv.astype(ctx.dtype),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, 1, h * m.v_head_dim).astype(x.dtype) @ params["wo"]
+
+
 def mla_decode(params: Params, x: jnp.ndarray, cache_ckv, cache_krope,
                lengths, cfg):
     """Absorbed-matrix MLA decode: attends in the 512-dim latent space.
 
     cache_ckv: (B, S_max, kv_lora); cache_krope: (B, S_max, rope_dim).
     """
-    m = cfg.mla
     b = x.shape[0]
-    h = cfg.num_heads
-    pos = lengths[:, None]
-    cq = rmsnorm(params["q_norm"], x @ params["w_dq"], cfg.rms_norm_eps)
-    q = (cq @ params["w_uq"]).reshape(
-        b, 1, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
-
-    dkv = x @ params["w_dkv"]
-    c_kv, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
-    c_kv = rmsnorm(params["kv_norm"], c_kv, cfg.rms_norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], pos, cfg.rope_theta)[:, :, 0, :]
-
+    q_nope, q_rope, c_kv, k_rope = _mla_qkv_full(params, x, lengths[:, None],
+                                                 cfg)
     bidx = jnp.arange(b)
     cache_ckv = cache_ckv.at[bidx, lengths].set(c_kv[:, 0])
     cache_krope = cache_krope.at[bidx, lengths].set(k_rope[:, 0])
 
-    # absorb W_uk into q:  q_lat (B,1,H,kv_lora)
-    w_uk = params["w_uk"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
-    q_lat = jnp.einsum("bshn,chn->bshc", q_nope, w_uk,
-                       preferred_element_type=jnp.float32)
+    q_lat = _absorbed_q(params, q_nope, cfg)               # (B, H, kv_lora)
     scores = (
-        jnp.einsum("bshc,btc->bhst", q_lat,
-                   cache_ckv.astype(jnp.float32),
+        jnp.einsum("bhc,btc->bht", q_lat, cache_ckv.astype(jnp.float32),
                    preferred_element_type=jnp.float32)
-        + jnp.einsum("bshr,btr->bhst", q_rope.astype(jnp.float32),
+        + jnp.einsum("bhr,btr->bht", q_rope[:, 0].astype(jnp.float32),
                      cache_krope.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
-    ) / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    ) * mla_softmax_scale(cfg)
     skv = cache_ckv.shape[1]
-    mask = jnp.arange(skv)[None, None, None, :] < (lengths + 1)[:, None, None, None]
+    mask = jnp.arange(skv)[None, None, :] < (lengths + 1)[:, None, None]
     scores = jnp.where(mask, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhst,btc->bshc", w, cache_ckv.astype(jnp.float32),
+    ctx = jnp.einsum("bht,btc->bhc", w, cache_ckv.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
-    w_uv = params["w_uv"].reshape(m.kv_lora_rank, h, m.v_head_dim)
-    out = jnp.einsum("bshc,chv->bshv", ctx, w_uv.astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
-    y = out.reshape(b, 1, h * m.v_head_dim).astype(x.dtype) @ params["wo"]
-    return y, cache_ckv, cache_krope
+    return _mla_out(params, ctx, x, cfg), cache_ckv, cache_krope
+
+
+def mla_decode_paged(params: Params, x: jnp.ndarray, pool, table, lengths,
+                     cfg, *, mode: str = BT.FLAT, layer=None):
+    """Absorbed-matrix MLA decode against a paged latent pool.
+
+    pool: (N_pages, page, latent_row(cfg)), rows of [c_kv | k_rope | 0],
+    or the pools of a stack's scanned layers (L, N_pages, page, row) with
+    ``layer`` this layer's index: appended and read in place, never
+    sliced out.  ``table`` is the flat (B, max_pages) map or a
+    RadixTable, translated once as for GQA.  The new token's row is
+    appended into the page the table maps; each head scores every row
+    with W_uk absorbed into its query (paged_latent_attention), then W_uv
+    and wo apply.  Returns (y, pool).
+    """
+    from repro.kernels import ops as KOPS
+    m = cfg.mla
+    b = x.shape[0]
+    page = pool.shape[-2]
+    pad = pool.shape[-1] - m.kv_lora_rank - m.qk_rope_head_dim
+    q_nope, q_rope, c_kv, k_rope = _mla_qkv_full(params, x, lengths[:, None],
+                                                 cfg)
+    phys_all = BT.translate_all(table, mode)              # (B, max_pages)
+    row = jnp.concatenate(
+        [c_kv[:, 0], k_rope[:, 0], jnp.zeros((b, pad), c_kv.dtype)], -1)
+    phys_new = jnp.maximum(phys_all[jnp.arange(b), lengths // page], 0)
+    pool = KVM.append_rows(pool, row.astype(pool.dtype), phys_new,
+                           lengths % page, layer=layer)
+    q_lat = _absorbed_q(params, q_nope, cfg)
+    q = jnp.concatenate(
+        [q_lat, q_rope[:, 0].astype(jnp.float32),
+         jnp.zeros(q_lat.shape[:2] + (pad,), jnp.float32)], -1)
+    ctx = KOPS.paged_latent_attention(
+        q.astype(pool.dtype), pool, phys_all, lengths + 1,
+        0 if layer is None else layer, kv_lora=m.kv_lora_rank,
+        scale=mla_softmax_scale(cfg))
+    return _mla_out(params, ctx, x, cfg), pool

@@ -59,15 +59,47 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float
-               ) -> jnp.ndarray:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, y) -> np.ndarray:
+    """DeepSeek-V2's YaRN inverse frequencies (``y``: a YarnConfig):
+    dimensions that turn fewer than ``beta_slow`` times over the original
+    context are interpolated (divided by ``factor``), those that turn
+    more than ``beta_fast`` times are kept, with a linear ramp between."""
+    def correction_dim(rotations):
+        return (dim * math.log(y.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction_dim(y.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    keep = 1.0 - ramp
+    return (extra / y.factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None) -> jnp.ndarray:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).
+    ``scaling``: a YarnConfig, whose frequencies and cos/sin scale apply."""
     if theta <= 0:  # archs without rope (whisper)
         return x
-    freqs = rope_frequencies(x.shape[-1], theta)           # (D/2,)
+    d = x.shape[-1]
+    freqs = (rope_frequencies(d, theta) if scaling is None
+             else jnp.asarray(yarn_frequencies(d, theta, scaling)))
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     angles = angles[..., None, :]                           # (..., S, 1, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -9,6 +9,8 @@ Entry points used by train/, serving/ and launch/:
   prefill(params, cfg, tokens, ...)          -> (logits, state)
 
 KV modes: "dense" | "paged_flat" (NDPage) | "paged_radix" (2-level baseline).
+For a stack with expert layers, a decode state's ``moe_rows_held`` is the
+last step's count of (token, expert) pairs computed by the held experts.
 """
 from __future__ import annotations
 
@@ -145,13 +147,20 @@ def init_decode_state(cfg: C.ArchConfig, batch: int, max_len: int,
     if cfg.is_encdec:
         state["enc_out"] = jnp.zeros(
             (batch, cfg.encoder_seq_len, cfg.d_model), dtype_of(cfg))
+    if T.has_moe(cfg):
+        # the last step's (token, expert) pairs computed by held experts
+        state["moe_rows_held"] = jnp.zeros((), jnp.int32)
     return state
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "kv_mode"))
+@functools.partial(jax.jit, static_argnames=("cfg", "kv_mode"),
+                   donate_argnames=("state",))
 def decode_step(params: Params, cfg: C.ArchConfig, state: Dict[str, Any],
                 tokens: jnp.ndarray, kv_mode: str = "dense"):
-    """One decode step. tokens: (B,) int32. Returns (logits (B, V), state)."""
+    """One decode step. tokens: (B,) int32. Returns (logits (B, V), state).
+
+    The state's buffers are donated to the new state's, so the caches are
+    appended in place: the caller keeps only the returned state."""
     lengths = state["lengths"]
     x = params["embed"][tokens][:, None, :]
     if cfg.rope_theta <= 0:
@@ -164,13 +173,15 @@ def decode_step(params: Params, cfg: C.ArchConfig, state: Dict[str, Any],
         x = x + pos_emb[:, None, :].astype(x.dtype)
 
     table = state.get("table")
-    x, new_stack = T.stack_apply_decode(
+    x, new_stack, rows = T.stack_apply_decode(
         params["stack"], state["stack"], x, lengths, cfg,
         kv_mode=kv_mode, table=table, enc_out=state.get("enc_out"))
     logits = _logits(params, cfg, x)[:, 0]
     new_state = dict(state)
     new_state["stack"] = new_stack
     new_state["lengths"] = lengths + 1
+    if rows is not None:
+        new_state["moe_rows_held"] = rows
     return logits, new_state
 
 

@@ -5,6 +5,11 @@ O(group * E * C) instead of O(T^2) — this is what makes MoE shardable and
 memory-bounded at 1M-token batches.  Experts shard over the `model` mesh
 axis (expert parallelism); the dispatch einsums lower to all-to-alls under
 pjit when tokens are data-sharded.
+
+A layer may hold only a share of the routed experts (``MoEConfig.
+first_expert`` / ``experts_held``, one chip of an expert-parallel
+deployment): it routes over all of them with the published rule and
+computes only its own experts' part, plus the shared experts.
 """
 from __future__ import annotations
 
@@ -35,11 +40,11 @@ def moe_init(key, cfg, dtype) -> Params:
 
     p: Params = {
         "router": dense_init(ks[0], d, m.num_experts, jnp.float32, scale),
-        "w_up": experts(ks[1], d, dff, m.num_experts),
-        "w_down": experts(ks[2], dff, d, m.num_experts),
+        "w_up": experts(ks[1], d, dff, m.held),
+        "w_down": experts(ks[2], dff, d, m.held),
     }
     if cfg.gated_ffn:
-        p["w_gate"] = experts(ks[3], d, dff, m.num_experts)
+        p["w_gate"] = experts(ks[3], d, dff, m.held)
     if m.num_shared_experts:
         sh = {}
         kk = jax.random.split(ks[4], 3)
@@ -64,20 +69,45 @@ def _expert_ffn(p: Params, x: jnp.ndarray, gated: bool) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", h, p["w_down"])
 
 
+def route(probs: jnp.ndarray, m) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The top-k experts of each token and their weights, (G, k) each.
+
+    probs: (G, E) router softmax.  With groups (DeepSeek-V2's
+    ``group_limited_greedy``), only experts of the ``topk_group`` groups
+    whose best expert scores highest compete.  The weights are the
+    chosen scores, renormalised to sum 1 or else scaled by
+    ``routed_scaling_factor``.
+    """
+    g, e = probs.shape
+    scores = probs
+    if m.n_group > 1:
+        best = probs.reshape(g, m.n_group, e // m.n_group).max(-1)
+        _, top_g = jax.lax.top_k(best, m.topk_group)
+        keep = jax.nn.one_hot(top_g, m.n_group, dtype=jnp.bool_).any(1)
+        scores = jnp.where(jnp.repeat(keep, e // m.n_group, axis=1),
+                           probs, 0.0)
+    top_p, top_i = jax.lax.top_k(scores, m.num_experts_per_tok)
+    if m.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    else:
+        top_p = top_p * m.routed_scaling_factor
+    return top_p, top_i
+
+
 def _route_group(params: Params, xg: jnp.ndarray, cfg
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One routing group. xg: (G, D) -> (out (G, D), aux loss scalar)."""
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One routing group. xg: (G, D) -> (out (G, D), aux loss scalar,
+    (token, expert) pairs the held experts computed)."""
     m = cfg.moe
     g, d = xg.shape
-    e, k = m.num_experts, m.num_experts_per_tok
+    e, k, held = m.num_experts, m.num_experts_per_tok, m.held
     # small groups (decode steps, smoke tests): exact dropless capacity;
     # large groups: capacity-factor routing (standard GShard behaviour)
     cap = g if g <= 64 else max(1, int(g * k * m.capacity_factor / e))
 
     logits = (xg.astype(jnp.float32) @ params["router"])          # (G, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                        # (G, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    top_p, top_i = route(probs, m)                                # (G, k)
 
     # load-balance aux loss (Switch): E * mean(frac_tokens * frac_probs)
     onehot = jax.nn.one_hot(top_i, e, dtype=jnp.float32)          # (G, k, E)
@@ -85,22 +115,25 @@ def _route_group(params: Params, xg: jnp.ndarray, cfg
     probs_per_e = probs.mean(axis=0)
     aux = e * jnp.sum(tokens_per_e * probs_per_e)
 
-    # capacity assignment: position of each (token, slot) in its expert queue
-    flat = onehot.reshape(g * k, e)
-    pos_in_e = jnp.cumsum(flat, axis=0) - flat                    # (G*k, E)
+    # the held experts' queues: position of each (token, slot) in its
+    # expert's queue; slots routed to experts held elsewhere take none
+    held_hot = jax.nn.one_hot(top_i - m.first_expert, held,
+                              dtype=jnp.float32)                  # (G, k, H)
+    flat = held_hot.reshape(g * k, held)
+    pos_in_e = jnp.cumsum(flat, axis=0) - flat                    # (G*k, H)
     pos = (pos_in_e * flat).sum(-1).reshape(g, k)                 # (G, k)
-    keep = (pos < cap) & (top_p > 0)
+    keep = (pos < cap) & (top_p > 0) & (held_hot.sum(-1) > 0)
     pos = jnp.minimum(pos, cap - 1)
 
-    # dispatch/combine tensors (G, E, C)
-    disp = (jax.nn.one_hot(top_i, e, dtype=xg.dtype)[..., None]
+    # dispatch/combine tensors (G, H, C)
+    disp = (held_hot.astype(xg.dtype)[..., None]
             * jax.nn.one_hot(pos, cap, dtype=xg.dtype)[..., None, :]
-            * keep[..., None, None].astype(xg.dtype))             # (G,k,E,C)
+            * keep[..., None, None].astype(xg.dtype))             # (G,k,H,C)
     combine = disp.astype(jnp.float32) * top_p[..., None, None]
-    disp = disp.sum(1)                                            # (G, E, C)
-    combine = combine.sum(1)                                      # (G, E, C)
+    disp = disp.sum(1)                                            # (G, H, C)
+    combine = combine.sum(1)                                      # (G, H, C)
 
-    expert_in = jnp.einsum("gec,gd->ecd", disp, xg)               # (E, C, D)
+    expert_in = jnp.einsum("gec,gd->ecd", disp, xg)               # (H, C, D)
     expert_in = constrain_act(expert_in, "model", None, None)     # EP
     expert_out = _expert_ffn(params, expert_in, cfg.gated_ffn)
     expert_out = constrain_act(expert_out, "model", None, None)
@@ -115,12 +148,14 @@ def _route_group(params: Params, xg: jnp.ndarray, cfg
         else:
             h = jax.nn.gelu(up.astype(jnp.float32)).astype(xg.dtype)
         out = out + h @ sh["w_down"]
-    return out, aux
+    return out, aux, keep.sum(dtype=jnp.int32)
 
 
 def moe_apply(params: Params, x: jnp.ndarray, cfg
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, D) -> (out, aux_loss). Groups along the flattened tokens."""
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """x: (B, S, D) -> (out, aux_loss, held rows). Groups along the
+    flattened tokens; held rows are the (token, expert) pairs that the
+    held experts computed."""
     b, s, d = x.shape
     t = b * s
     gsz = min(GROUP_SIZE, t)
@@ -132,7 +167,7 @@ def moe_apply(params: Params, x: jnp.ndarray, cfg
         return None, _route_group(params, xi, cfg)
 
     if ng == 1:
-        out, aux = _route_group(params, xg[0], cfg)
-        return out.reshape(b, s, d), aux
-    _, (outs, auxs) = jax.lax.scan(body, None, xg)
-    return outs.reshape(b, s, d), auxs.mean()
+        out, aux, rows = _route_group(params, xg[0], cfg)
+        return out.reshape(b, s, d), aux, rows
+    _, (outs, auxs, rows) = jax.lax.scan(body, None, xg)
+    return outs.reshape(b, s, d), auxs.mean(), rows.sum()

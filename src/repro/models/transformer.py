@@ -9,6 +9,15 @@ Decode state mirrors the params tree: {"prefix": [block_state...],
   dense       contiguous per-layer KV cache (the no-translation baseline)
   paged_flat  NDPage flattened single-level block table (one indirection)
   paged_radix 2-level directory->leaf block table (two indirections)
+Latent attention (MLA) pages one row a token, shared by every head, in a
+pool of its own per layer; GQA pages K and V.  The paged pools of the
+scanned layers ride the scan's carry whole, stacked over periods, and
+each layer appends its new token into its own in place by its index
+(the decode state is donated), so no layer writes a pool back.
+
+In decode, latent attention runs under the named scope ``mla`` and a
+routed expert layer under ``moe``, which the compiled step's op metadata
+carries to a device trace's reader.
 """
 from __future__ import annotations
 
@@ -68,7 +77,7 @@ def _apply_ffn(bp: Params, h: jnp.ndarray, cfg, ffn_kind: str,
                shift_prev: Optional[jnp.ndarray] = None):
     """Returns (y, aux)."""
     if ffn_kind == C.MOE_FF:
-        return MOE.moe_apply(bp["ffn"], h, cfg)
+        return MOE.moe_apply(bp["ffn"], h, cfg)[:2]
     if cfg.rwkv is not None:
         if shift_prev is None:  # train: shift along seq
             prev = jnp.pad(h, ((0, 0), (1, 0), (0, 0)))[:, :-1]
@@ -124,8 +133,12 @@ def block_init_state(cfg, mixer_kind: str, ffn_kind: str, batch: int,
             st["vp"] = jnp.zeros((pages_per_layer, page_size, k, hd), dt)
     elif mixer_kind == C.ATTN_MLA:
         m = cfg.mla
-        st["ckv"] = jnp.zeros((batch, max_len, m.kv_lora_rank), dt)
-        st["kr"] = jnp.zeros((batch, max_len, m.qk_rope_head_dim), dt)
+        if kv_mode == "dense":
+            st["ckv"] = jnp.zeros((batch, max_len, m.kv_lora_rank), dt)
+            st["kr"] = jnp.zeros((batch, max_len, m.qk_rope_head_dim), dt)
+        else:
+            st["lat"] = jnp.zeros(
+                (pages_per_layer, page_size, A.latent_row(cfg)), dt)
     elif mixer_kind == C.MAMBA:
         st.update(M.mamba_init_state(cfg, batch))
     elif mixer_kind == C.RWKV:
@@ -137,8 +150,10 @@ def block_init_state(cfg, mixer_kind: str, ffn_kind: str, batch: int,
 
 def block_apply_decode(bp: Params, st, x, lengths, cfg,
                        mixer_kind: str, ffn_kind: str, kv_mode: str,
-                       table=None, enc_out=None):
-    """x: (B,1,D). Returns (x', new_state, aux)."""
+                       table=None, enc_out=None, layer=None):
+    """x: (B,1,D). Returns (x', new_state, held expert rows or None).
+    ``layer``: this layer's index in pools stacked over the scanned
+    layers (``st["kp"]``, ``st["vp"]``, ``st["lat"]``)."""
     x = constrain_act(x, BATCH, None, None)
     h = rmsnorm(bp["norm1"], x, cfg.rms_norm_eps)
     new_st = dict(st)
@@ -151,12 +166,18 @@ def block_apply_decode(bp: Params, st, x, lengths, cfg,
         else:
             y, kp, vp = A.attn_decode_paged(
                 bp["mixer"], h, st["kp"], st["vp"], table, lengths, cfg,
-                window=window, mode=kv_mode)
+                window=window, mode=kv_mode, layer=layer)
             new_st["kp"], new_st["vp"] = kp, vp
     elif mixer_kind == C.ATTN_MLA:
-        y, ckv, kr = A.mla_decode(bp["mixer"], h, st["ckv"], st["kr"],
-                                  lengths, cfg)
-        new_st["ckv"], new_st["kr"] = ckv, kr
+        with jax.named_scope("mla"):
+            if kv_mode == "dense":
+                y, ckv, kr = A.mla_decode(bp["mixer"], h, st["ckv"],
+                                          st["kr"], lengths, cfg)
+                new_st["ckv"], new_st["kr"] = ckv, kr
+            else:
+                y, new_st["lat"] = A.mla_decode_paged(
+                    bp["mixer"], h, st["lat"], table, lengths, cfg,
+                    mode=kv_mode, layer=layer)
     elif mixer_kind == C.MAMBA:
         y, ms = M.mamba_decode(bp["mixer"], h,
                                {"conv": st["conv"], "ssm": st["ssm"]}, cfg)
@@ -173,11 +194,15 @@ def block_apply_decode(bp: Params, st, x, lengths, cfg,
         ek, ev = A.cross_kv(bp["cross"], enc_out, cfg)
         x = x + A.cross_attn_apply(bp["cross"], hc, ek, ev, cfg)
     h2 = rmsnorm(bp["norm2"], x, cfg.rms_norm_eps)
+    if ffn_kind == C.MOE_FF:
+        with jax.named_scope("moe"):
+            y2, _, rows = MOE.moe_apply(bp["ffn"], h2, cfg)
+        return x + y2, new_st, rows
     shift_prev = st.get("ffn_shift")
-    y2, aux = _apply_ffn(bp, h2, cfg, ffn_kind, shift_prev=shift_prev)
+    y2, _ = _apply_ffn(bp, h2, cfg, ffn_kind, shift_prev=shift_prev)
     if shift_prev is not None:
         new_st["ffn_shift"] = h2[:, 0].astype(jnp.float32)
-    return x + y2, new_st, aux
+    return x + y2, new_st, None
 
 
 # ---------------------------------------------------------------------------
@@ -246,30 +271,78 @@ def stack_init_state(cfg, batch: int, max_len: int, kv_mode: str,
     return {"prefix": prefix, "scan": scan}
 
 
+#: decode-state entries that are paged pools: GQA's K and V, MLA's latent
+PAGED_POOLS = ("kp", "vp", "lat")
+
+
+def has_moe(cfg) -> bool:
+    return any(fk == C.MOE_FF for _, fk in cfg.layer_kinds())
+
+
 def stack_apply_decode(params: Params, state, x, lengths, cfg, *,
                        kv_mode: str, table=None, enc_out=None):
-    """x: (B,1,D). Returns (x, new_state)."""
+    """x: (B,1,D). Returns (x, new_state, rows): rows are the (token,
+    expert) pairs the held routed experts computed, summed over layers,
+    for a stack with expert layers, else None."""
     pattern = list(cfg.layer_pattern)
+    moe = has_moe(cfg)
+    rows = jnp.int32(0) if moe else None
+
+    def add(total, r):
+        return total + r if r is not None else total
+
     new_prefix = []
     for bp, st, (mk, fk) in zip(params["prefix"], state["prefix"],
                                 cfg.prefix_pattern):
-        x, nst, _ = block_apply_decode(bp, st, x, lengths, cfg, mk, fk,
+        x, nst, r = block_apply_decode(bp, st, x, lengths, cfg, mk, fk,
                                        kv_mode, table, enc_out)
+        rows = add(rows, r)
         new_prefix.append(nst)
 
     if cfg.num_periods == 0:
-        return x, {"prefix": new_prefix, "scan": {}}
+        return x, {"prefix": new_prefix, "scan": {}}, rows
 
-    def period_body(x, inp):
-        pp, pst = inp
+    # scanned paged pools are carried whole (see the module docstring)
+    pools = {} if kv_mode == "dense" else {
+        name: {k: v for k, v in st.items() if k in PAGED_POOLS}
+        for name, st in state["scan"].items()}
+    pools = {name: p for name, p in pools.items() if p}
+    xs_state = {name: {k: v for k, v in st.items()
+                       if k not in pools.get(name, {})}
+                for name, st in state["scan"].items()}
+
+    def period_body(carry, inp):
+        x = carry["x"]
+        rows = carry.get("rows")
+        pools = {name: dict(p) for name, p in carry.get("pools", {}).items()}
+        pp, pst = inp[:2]
         new_pst = {}
         for j, (mk, fk) in enumerate(pattern):
-            x, nst, _ = block_apply_decode(
-                pp[f"block_{j}"], pst[f"block_{j}"], x, lengths, cfg,
-                mk, fk, kv_mode, table, enc_out)
-            new_pst[f"block_{j}"] = nst
-        return x, new_pst
+            name = f"block_{j}"
+            own = pools.get(name, {})
+            x, nst, r = block_apply_decode(
+                pp[name], dict(pst[name], **own), x, lengths, cfg, mk, fk,
+                kv_mode, table, enc_out, layer=inp[2] if own else None)
+            rows = add(rows, r)
+            for k in own:
+                own[k] = nst.pop(k)
+            new_pst[name] = nst
+        out = {"x": x}
+        if moe:
+            out["rows"] = rows
+        if pools:
+            out["pools"] = pools
+        return out, new_pst
 
-    x, new_scan = jax.lax.scan(period_body, x,
-                               (params["scan"], state["scan"]))
-    return x, {"prefix": new_prefix, "scan": new_scan}
+    carry = {"x": x}
+    if moe:
+        carry["rows"] = rows
+    xs = (params["scan"], xs_state)
+    if pools:
+        carry["pools"] = pools
+        xs += (jnp.arange(cfg.num_periods),)
+    carry, new_scan = jax.lax.scan(period_body, carry, xs)
+    for name, p in carry.get("pools", {}).items():
+        new_scan[name] = dict(new_scan[name], **p)
+    return carry["x"], {"prefix": new_prefix, "scan": new_scan}, \
+        carry.get("rows")

@@ -33,6 +33,12 @@ from repro.serving._scheduler import BatchScheduler, Request
 from repro.util import resilience, spans
 
 
+@jax.jit
+def _greedy(logits: jnp.ndarray) -> jnp.ndarray:
+    """(B, V) logits -> (B,) int32 argmax, the first of tied maxima."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 class ServeEngine:
     def __init__(self, cfg, params, *, max_batch: int = 8,
                  max_len: int = 256, page_size: int = 16,
@@ -134,7 +140,8 @@ class ServeEngine:
             sp.set(rows=tc.misses - misses, hits=tc.hits - hits)
             # what each layer's paged-attention call fetches (the pages
             # of every slot's live range, the new token's included) out
-            # of what a dense walk of the table would
+            # of what a dense walk of the table would; K/V pages for GQA,
+            # latent pages for MLA, which share the table
             spans.count("repro.serve.kv_pages_live",
                         int((-(-(lens + 1) // self.page_size)).sum()))
             spans.count("repro.serve.kv_pages_table",
@@ -147,8 +154,18 @@ class ServeEngine:
             logits, new_state = decode_step(self.params, self.cfg, state,
                                             tokens, kv_mode=mode)
             self.state = dict(new_state)
+            # greedy choice on the device: the host fetches one id a
+            # slot, not the (B, V) logits
+            chosen = _greedy(logits)
         with spans.span("repro.serve.device_wait", step=step):
-            logits = np.asarray(logits)
+            rows = new_state.get("moe_rows_held")
+            if rows is None:
+                chosen = np.asarray(chosen)
+            else:
+                # (token, expert) pairs the held experts computed this
+                # step, over the layers: in the ids' transfer
+                chosen, rows = jax.device_get((chosen, rows))
+                spans.count("repro.serve.moe_rows_held", int(rows))
 
         with spans.span("repro.serve.sample", step=step):
             produced: Dict[int, int] = {}
@@ -161,7 +178,7 @@ class ServeEngine:
                     # teacher-forced prompt consumption
                     self._next_token[slot] = int(stream[pos])
                 else:
-                    nxt = int(np.argmax(logits[slot]))
+                    nxt = int(chosen[slot])
                     self._next_token[slot] = nxt
                     produced[sid] = nxt
             return self.sched.record_tokens(produced)

@@ -107,6 +107,78 @@ def test_decode_step_reads_the_kernel_through_a_bitcast(one_chip,
     assert uses and all(" bitcast(" in line for line in uses), uses
 
 
+def test_decode_step_appends_pools_in_place(one_chip, monkeypatch):
+    """The served decode step (internlm2-1.8b widths, two scanned layers,
+    the one-chip deployment) is handed the state's buffers and appends
+    each layer's new K/V into the stacked pools in place: the pools come
+    back in the buffers they came in, and no op copies or writes back a
+    pool or one layer of it."""
+    import dataclasses
+    import repro.kernels.ops as ops
+    from repro.core import block_table as BT
+    from repro.models import decode_step, init_decode_state, init_params
+    monkeypatch.setattr(ops, "_impl", lambda override: "pallas")
+    cfg = dataclasses.replace(C.get_arch("internlm2-1.8b"), num_layers=2)
+    on_chip = lambda tree: jax.tree.map(                     # noqa: E731
+        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    state = on_chip(jax.eval_shape(lambda: init_decode_state(
+        cfg, 8, 2048, BT.FLAT, 16, num_pages=8 * 128 + 8)))
+    kp = state["stack"]["scan"]["block_0"]["kp"]
+    assert kp.shape == (2, 8 * 128 + 8, 16, 8, 128)
+    pools = 2 * kp.size * kp.dtype.itemsize
+    tokens = _spec(one_chip, (8,), jnp.int32)
+    compiled = decode_step.lower(params, cfg, state, tokens,
+                                 BT.FLAT).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools // 4       # under one layer's
+    pool = re.compile(r"bf16\[(2,)?1032,16,8,128\]")
+    rewritten = [
+        line for line in compiled.as_text().splitlines()
+        if (m := re.match(r"\s*(ROOT )?%\S+ = (\S+) ([\w-]+)\(", line))
+        and pool.match(m.group(2))
+        and m.group(3) in ("copy", "dynamic-update-slice")]
+    assert not rewritten, rewritten
+
+
+def test_deepseek_v2_decode_step_names_the_latent_kernel(one_chip,
+                                                        monkeypatch):
+    """DeepSeek-V2's decode step at its published widths (the dense layer
+    and one expert layer holding 8 of 160 experts; 64 slots of 4096
+    tokens in 16-token latent pages): one latent kernel per layer, named
+    ``paged_latent_attention_kernel`` (what ``mla_paged_roofline``
+    matches in an op's own name), which never carries the GQA kernel's
+    name; expert and attention ops carry their named scopes."""
+    import dataclasses
+    import repro.kernels.ops as ops
+    from repro.core import block_table as BT
+    from repro.models import decode_step, init_decode_state, init_params
+    monkeypatch.setattr(ops, "_impl", lambda override: "pallas")
+    full = C.get_arch("deepseek-v2-236b")
+    cfg = dataclasses.replace(full, num_layers=2, moe=dataclasses.replace(
+        full.moe, experts_held=8))
+    on_chip = lambda tree: jax.tree.map(                     # noqa: E731
+        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    state = on_chip(jax.eval_shape(lambda: init_decode_state(
+        cfg, 64, 4096, BT.FLAT, 16, num_pages=64 * 256 + 8)))
+    assert state["stack"]["prefix"][0]["lat"].shape == (64 * 256 + 8, 16,
+                                                          640)
+    tokens = _spec(one_chip, (64,), jnp.int32)
+    text = decode_step.lower(params, cfg, state, tokens, BT.FLAT).compile(
+        ).as_text()
+    names = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*custom-call\(.*"
+                       r"tpu_custom_call", text, re.M)
+    kernel = [n for n in names if "paged_latent_attention_kernel" in n]
+    assert len(kernel) == 2, names
+    assert not any("paged_attention_pallas" in n for n in names)
+    assert 'op_name="jit(decode_step)/while/body/closed_call/moe/' in text
+    assert "/mla/" in text
+
+
 def test_flash_attention_s2048(one_chip):
     from repro.kernels.flash_attention import flash_attention_pallas
     cfg = C.get_arch("internlm2-1.8b")
