@@ -35,15 +35,17 @@ def _impl(override: Optional[str]) -> str:
 
 def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_pages: jnp.ndarray, block_table: jnp.ndarray,
-                    valid_lens: jnp.ndarray, *, window: int = 0,
+                    valid_lens: jnp.ndarray, layer=0, *, window: int = 0,
                     impl: Optional[str] = None) -> jnp.ndarray:
-    """Decode attention over paged KV (see kernels/paged_attention.py)."""
+    """Decode attention over paged KV pools, (N, page, K, D) or stacked
+    (L, N, page, K, D) and read at ``layer`` (see
+    kernels/paged_attention.py)."""
     which = _impl(impl)
     if which == "ref" or (which == "pallas" and not pages_tile(k_pages)):
         return ref.paged_attention_ref(q, k_pages, v_pages, block_table,
-                                       valid_lens, window=window)
+                                       valid_lens, layer, window=window)
     return paged_attention_pallas(q, k_pages, v_pages, block_table,
-                                  valid_lens, window=window,
+                                  valid_lens, layer, window=window,
                                   interpret=(which == "interpret"))
 
 

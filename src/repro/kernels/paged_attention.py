@@ -16,9 +16,11 @@ The paper's two ideas, re-expressed in the TPU memory hierarchy:
 Layouts:
   q: (B, H, D)   one decode token per sequence, H = KH * G query heads
   out: (B, KH, G, D)
-  k/v pools: (N, page, KH, D), read in place from HBM
+  k/v pools: (N, page, KH, D), or (L, N, page, KH, D) stacked over
+        layers, read in place from HBM
   block_table: (B, MAXP) int32 (-1 = unmapped)   [scalar prefetch]
   lengths: (B,) int32 attendable tokens           [scalar prefetch]
+  layer: (1,) int32, the layer of a stacked pool  [scalar prefetch]
 
 Work scales with the live pages, not with MAXP.  The scalar core lists
 every live block — ``ppb`` pages of one sequence, ppb derived from the
@@ -51,7 +53,7 @@ def pages_tile(k_pages) -> bool:
     """Whether the compiled kernel can DMA a page of this pool: Mosaic
     slices a pool by pages only where a page's (KH, D) lie on whole
     (8, 128) tiles.  Interpret mode takes any widths."""
-    _, _, kh, d = k_pages.shape
+    kh, d = k_pages.shape[-2:]
     return kh % 8 == 0 and d % 128 == 0
 
 
@@ -63,7 +65,7 @@ def block_pages(page: int, kh: int, maxp: int) -> int:
     return ppb
 
 
-def _kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, sems, work_b, work_i, m_ref, l_ref, acc_ref,
             *, page: int, ppb: int, kh: int, g: int, window: int,
             scale: float):
@@ -100,7 +102,9 @@ def _kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             def _():
                 pid = table_ref[b, p]
                 for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
-                    cp = pltpu.make_async_copy(hbm.at[pid], buf.at[slot, i],
+                    src = (hbm.at[layer_ref[0], pid] if len(hbm.shape) == 5
+                           else hbm.at[pid])
+                    cp = pltpu.make_async_copy(src, buf.at[slot, i],
                                                sems.at[slot])
                     cp.wait() if wait else cp.start()
 
@@ -167,18 +171,20 @@ def _kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, block_table: jnp.ndarray,
-                           lengths: jnp.ndarray, *, window: int = 0,
+                           lengths: jnp.ndarray, layer=0, *,
+                           window: int = 0,
                            interpret: bool = False) -> jnp.ndarray:
-    """q: (B, 1, H, D); k/v_pages: (N, page, K, D); block_table: (B, MAXP).
+    """q: (B, 1, H, D); k/v_pages: (N, page, K, D) or (L, N, page, K, D)
+    with ``layer`` the one to read; block_table: (B, MAXP).
 
     Returns (B, 1, H, D).  ``interpret=True`` runs the kernel body on CPU
     for validation (this container); on TPU it compiles to Mosaic.
     """
     if not (interpret or pages_tile(k_pages)):
-        raise ValueError(f"pages of shape {k_pages.shape[1:]} do not lie "
+        raise ValueError(f"pages of shape {k_pages.shape[-3:]} do not lie "
                          "on whole (8, 128) tiles: use the jnp reference")
     b, _, h, d = q.shape
-    _, page, kh, _ = k_pages.shape
+    page, kh, _ = k_pages.shape[-3:]
     g = h // kh
     maxp = block_table.shape[1]
     ppb = block_pages(page, kh, maxp)
@@ -188,7 +194,7 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     # kernel as an operand and carries its name in the op's text
     out_whole = lambda *_: (0,) * 4                  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(1,),
         in_specs=[
             pl.BlockSpec((b, h, d), whole),
@@ -218,5 +224,6 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
         # the kernel's own name in compiled programs and device traces,
         # whatever jitted function calls it
         name="paged_attention_pallas_kernel",
-    )(block_table, lengths, q.reshape(b, h, d), k_pages, v_pages)
+    )(block_table, lengths, jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+      q.reshape(b, h, d), k_pages, v_pages)
     return out.reshape(b, 1, h, d)

@@ -16,17 +16,20 @@ NEG_INF = -1e30
 
 def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                         v_pages: jnp.ndarray, block_table: jnp.ndarray,
-                        valid_lens: jnp.ndarray, *, window: int = 0
-                        ) -> jnp.ndarray:
+                        valid_lens: jnp.ndarray, layer=0, *,
+                        window: int = 0) -> jnp.ndarray:
     """Decode attention over paged KV.
 
     q: (B, 1, H, D) one query token per sequence
-    k_pages/v_pages: (N, page, K, D) physical pools
+    k_pages/v_pages: (N, page, K, D) physical pools, or (L, N, page, K, D)
+      of which ``layer`` is read
     block_table: (B, max_pages) int32 physical page ids (-1 = unmapped)
     valid_lens: (B,) number of attendable tokens (incl. the new one)
     window: if > 0, only the last `window` tokens are attendable.
     Returns (B, 1, H, D).
     """
+    if k_pages.ndim == 5:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     b, s1, h, d = q.shape
     n, page, kh, _ = k_pages.shape
     g = h // kh

@@ -270,11 +270,11 @@ def attn_decode_paged(params: Params, x: jnp.ndarray, kp, vp, table,
 
     kp/vp: (N_pages, page, K, D) pools, or the pools of a stack's scanned
     layers (L, N_pages, page, K, D) with ``layer`` this layer's index: the
-    new token is appended in place and the kernel reads the layer's pools
-    sliced out.  ``table`` is a flat (B, max_pages) map (NDPage) or a
-    RadixTable (2-level baseline).  The table translate is the
-    address-translation step; flat mode does ONE indirection, radix TWO.
-    Returns (y, kp, vp).
+    new token is appended in place and the kernel reads the layer's pages
+    out of the stack at that index.  ``table`` is a flat (B, max_pages)
+    map (NDPage) or a RadixTable (2-level baseline).  The table translate
+    is the address-translation step; flat mode does ONE indirection, radix
+    TWO.  Returns (y, kp, vp).
 
     With a mesh installed (repro.parallel.context) the data path runs under
     shard_map with SHARD-LOCAL paging (perf iteration H4): sequences are
@@ -314,8 +314,8 @@ def attn_decode_paged(params: Params, x: jnp.ndarray, kp, vp, table,
                                jnp.maximum(phys_new, 0), lengths % page,
                                layer=layer)
         from repro.kernels import ops as KOPS
-        kl, vl = (kp, vp) if layer is None else (kp[layer], vp[layer])
-        out = KOPS.paged_attention(q, kl, vl, phys_all, lengths + 1,
+        out = KOPS.paged_attention(q, kp, vp, phys_all, lengths + 1,
+                                   0 if layer is None else layer,
                                    window=window)
     # contract (heads, head_dim) against wo without flattening so an
     # hd-sharded attention output psums once into (B, 1, D)

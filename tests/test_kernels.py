@@ -69,13 +69,36 @@ class TestPagedAttentionKernel:
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             **_tol(dtype))
 
-    @pytest.mark.parametrize("window", [8, 40])
-    def test_windowed(self, window):
+    @pytest.mark.parametrize("window,layer", [
+        pytest.param(8, None, id="8"),
+        pytest.param(40, None, id="40"),
+        # pools stacked over 3 layers, read at the first, a middle and
+        # the last, with and without a window
+        *[pytest.param(w, i, id=f"{w}-stacked{i}")
+          for w in (0, 40) for i in range(3)],
+    ])
+    def test_windowed(self, window, layer):
         q, kp, vp, tab, lens = _paged_inputs(2, 4, 2, 64, 16, 6,
                                              jnp.float32, seed=3)
         want = ref.paged_attention_ref(q, kp, vp, tab, lens, window=window)
         got = paged_attention_pallas(q, kp, vp, tab, lens, window=window,
                                      interpret=True)
+        if layer is not None:
+            # every other layer holds different data: reading the wrong
+            # one fails the comparison; the layer's own output is the
+            # unstacked call's, bit for bit
+            ks = jax.random.split(jax.random.PRNGKey(11), 2)
+            other = jax.random.normal(ks[0], (2,) + kp.shape, kp.dtype)
+            kst = jnp.insert(other, layer, kp, axis=0)
+            vst = jnp.insert(-other[::-1], layer, vp, axis=0)
+            stacked = paged_attention_pallas(q, kst, vst, tab, lens, layer,
+                                             window=window, interpret=True)
+            np.testing.assert_array_equal(np.asarray(stacked),
+                                          np.asarray(got))
+            np.testing.assert_array_equal(np.asarray(ref.paged_attention_ref(
+                q, kst, vst, tab, lens, layer, window=window)),
+                np.asarray(want))
+            got = stacked
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 

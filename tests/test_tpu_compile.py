@@ -52,30 +52,37 @@ def _spec(sharding, shape, dtype):
 
 def test_paged_attention_internlm2_widths(one_chip):
     """The one-chip serving deployment: 8 sequences of up to 2048 tokens
-    in 16-token bf16 pages (128 pages each, 1032 in the pool)."""
+    in 16-token bf16 pages (128 pages each, 1032 in the pool), one
+    layer's pools or the pools of every layer stacked and read at a
+    layer index."""
     from repro.kernels.paged_attention import paged_attention_pallas
     cfg = C.get_arch("internlm2-1.8b")
     b, maxp, page = 8, 128, 16
     q = _spec(one_chip, (b, 1, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
-    kv = _spec(one_chip, (b * maxp + 8, page, cfg.num_kv_heads,
-                          cfg.head_dim), jnp.bfloat16)
+    shape = (b * maxp + 8, page, cfg.num_kv_heads, cfg.head_dim)
     table = _spec(one_chip, (b, maxp), jnp.int32)
     lens = _spec(one_chip, (b,), jnp.int32)
-    text = paged_attention_pallas.lower(q, kv, kv, table, lens).compile(
-        ).as_text()
-    assert KERNEL_MARKER in text
-    # the kernel's own name, not its jitted wrapper's, names the custom
-    # call: the op a device trace shows
-    call = re.search(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", text)
-    assert call and call.group(1).startswith("paged_attention_pallas_kernel")
-    # the kernel reads the pools in place: no instruction outside it
-    # makes a pool-sized array, in the pools' layout or transposed
-    pool = re.compile(r"bf16\[(%d,%d,%d,%d|%d,%d,%d,%d)\]" % (
-        kv.shape + (kv.shape[2], kv.shape[0], page, cfg.head_dim)))
-    made = [line for line in text.splitlines()
-            if (m := re.match(r"\s*(ROOT )?%\S+ = (\S+) ([\w-]+)\(", line))
-            and pool.match(m.group(2)) and m.group(3) != "parameter"]
-    assert not made, made
+    layer = _spec(one_chip, (), jnp.int32)
+    # no instruction outside the kernel makes a pool-sized array (one
+    # layer's or the stack's), in the pools' layout or transposed
+    pool = re.compile(r"bf16\[(\d+,)?(%d,%d,%d,%d|%d,%d,%d,%d)\]" % (
+        shape + (shape[2], shape[0], page, cfg.head_dim)))
+    for kv_shape in (shape, (cfg.num_layers,) + shape):
+        kv = _spec(one_chip, kv_shape, jnp.bfloat16)
+        text = paged_attention_pallas.lower(q, kv, kv, table, lens,
+                                            layer).compile().as_text()
+        assert KERNEL_MARKER in text
+        # the kernel's own name, not its jitted wrapper's, names the
+        # custom call: the op a device trace shows
+        call = re.search(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call",
+                         text)
+        assert call and call.group(1).startswith(
+            "paged_attention_pallas_kernel")
+        made = [line for line in text.splitlines()
+                if (m := re.match(r"\s*(ROOT )?%\S+ = (\S+) ([\w-]+)\(",
+                                  line))
+                and pool.match(m.group(2)) and m.group(3) != "parameter"]
+        assert not made, made
 
 
 def test_decode_step_reads_the_kernel_through_a_bitcast(one_chip,
@@ -111,8 +118,9 @@ def test_decode_step_appends_pools_in_place(one_chip, monkeypatch):
     """The served decode step (internlm2-1.8b widths, two scanned layers,
     the one-chip deployment) is handed the state's buffers and appends
     each layer's new K/V into the stacked pools in place: the pools come
-    back in the buffers they came in, and no op copies or writes back a
-    pool or one layer of it."""
+    back in the buffers they came in, no op copies or writes back a pool
+    or one layer of it, and no op makes one layer's pools: the kernel
+    reads them out of the stack at the layer's index."""
     import dataclasses
     import repro.kernels.ops as ops
     from repro.core import block_table as BT
@@ -141,6 +149,13 @@ def test_decode_step_appends_pools_in_place(one_chip, monkeypatch):
         and pool.match(m.group(2))
         and m.group(3) in ("copy", "dynamic-update-slice")]
     assert not rewritten, rewritten
+    layer = re.compile(r"bf16\[(1,)?1032,16,8,128\]")
+    sliced = [
+        line for line in compiled.as_text().splitlines()
+        if (m := re.match(r"\s*(ROOT )?%\S+ = (\S+) ([\w-]+)\(", line))
+        and layer.match(m.group(2))
+        and m.group(3) not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not sliced, sliced
 
 
 def test_deepseek_v2_decode_step_names_the_latent_kernel(one_chip,
