@@ -6,7 +6,7 @@ used to mean hand-editing ``MachineConfig`` and paying one compile per
 variant.  :func:`sweep` takes a declarative grid over machine
 parameters × mechanisms × workloads, buckets the cross-product by
 *compiled shape* (``machine_shape`` + mechanism walk-fn tuple), and
-runs each bucket as ONE batched chunked-scan dispatch via
+runs each bucket as ONE chunked-scan dispatch via
 :func:`repro.sim.simulator.simulate_batch_varied`.  Parameter values
 that don't change array shapes — latencies, memory service time,
 bypass/PWC/huge flags, walk depth — ride the batch lanes as data, so
@@ -489,7 +489,7 @@ def sweep(grid: GridLike, *, base: str | None = None,
           devices: int | None = None,
           checkpoint: "bool | str | None" = None,
           watchdog_s: float | None = None) -> SweepResult:
-    """Run a sensitivity grid, one batched dispatch per shape bucket.
+    """Run a sensitivity grid, one dispatch per shape bucket.
 
     ``grid`` is an ordered ``axis -> values`` mapping (see module
     docstring) or the name of a preset in ``configs.ndp_sim.SWEEPS``

@@ -13,9 +13,8 @@ Every address-translation mechanism the simulator can evaluate is ONE
   * the function mapping a VPN to the PTE cache-line ids its walk touches
     (from :mod:`repro.core.page_table`).
 
-``simulator.py``, ``cache_model.py`` callers, ``configs/ndp_sim.py``,
-``benchmarks/sim_figures.py`` and the tests all consume the one registry
-below; adding a mechanism is a single ``register(MechanismSpec(...))`` —
+``simulator.py``, ``configs/ndp_sim.py``, ``benchmarks/sim_figures.py``
+and the tests all consume the one registry below; adding a mechanism is a single ``register(MechanismSpec(...))`` —
 see ``ndpage_pl3`` at the bottom for a worked example (a flattened-PL3
 NDPage variant that merges L3/L2/L1 into one giant node).
 

@@ -24,19 +24,20 @@ mechanism there is all it takes to simulate it.
 
 Engine
 ------
-A chunked ``jax.lax.scan``, split along the only real serial dependency:
+ONE jitted chunk runner (:func:`_chunk_runner`), a chunked
+``jax.lax.scan`` split along the only real serial dependency:
 
 * the **scan** carries nothing but the LRU tag/stamp tables and performs
-  the cache/TLB/PWC lookups (the state evolution that must be
-  sequential), emitting one packed int32 of hit bits per (mech, core)
-  per entry;
+  the cache/TLB/PWC lookups (:func:`lru_access`, the state evolution that
+  must be sequential), emitting one packed int32 of hit bits per (mech,
+  lane) per entry;
 * a vectorized **epilogue** (same jit) expands the hit bits over the
   whole chunk at once and does every latency/counter computation there —
   the per-step graph stays tiny, which is what per-op-overhead-bound CPU
   backends need.
 
 The trace is pre-generated, padded to fixed-shape chunks, and streamed
-through ONE jitted runner whose state buffers are donated between chunks.
+through the runner, whose state buffers are donated between chunks.
 The runner is compiled once per (machine SHAPE, mechanism walk-fn tuple,
 chunk length) — trace length never retriggers compilation, and neither
 does any value-like machine parameter: :class:`MachineShape` captures
@@ -52,21 +53,21 @@ is what makes the split exact.
 
 Batch axis
 ----------
-:func:`simulate_batch` adds a batch axis over B *independent
-simulations* that share one ``MachineConfig`` shape (e.g. all Table-II
-workloads at a given machine × core count): the whole bucket runs as ONE
-chunked-scan dispatch.  LRU tables are laid out ``(B, C, M, sets,
-ways)`` — every mapped axis stays leading, so no per-step transpose is
-ever materialized (the same rule that drove the (C, M) layout) — and
-are reshaped (free: the leading axes are contiguous) onto the fused
-``(B*C, M, sets, ways)`` lane layout at dispatch: independent sims are
-exactly the proven two-level engine with a wider lane axis, which
-XLA-CPU runs at full width, whereas a literal third vmap level regresses
-the per-step gathers ~2x.  Per-sim queue windows, valid masks (lanes may
-have different true trace lengths), and counters stay per-sim and are
-sliced back into per-sim :class:`SimResult` objects at the end; results
-are bit-exact vs per-sim :func:`simulate` — lanes never interact.
-When more than one XLA host device is available (opt-in via
+Every run is a batch of B *independent simulations* that share one
+``MachineConfig`` shape (e.g. all Table-II workloads at a given machine
+× core count): :func:`simulate_batch` runs the whole bucket as ONE
+chunked-scan dispatch, and :func:`simulate` is the batch of one.  LRU
+tables are laid out ``(B, C, M, sets, ways)`` — every mapped axis stays
+leading, so no per-step transpose is ever materialized — and are
+reshaped (free: the leading axes are contiguous) onto the fused
+``(B*C, M, sets, ways)`` lane layout at dispatch: two vmap levels (lanes
+× mechanisms) over a wide lane axis, which XLA-CPU runs at full width,
+whereas a literal third vmap level regresses the per-step gathers ~2x.
+Per-sim queue windows, valid masks (lanes may have different true trace
+lengths), and counters stay per-sim and are sliced back into per-sim
+:class:`SimResult` objects at the end; lanes never interact, so a
+lane's result does not depend on its neighbours or its place in the
+batch.  When more than one XLA host device is available (opt-in via
 ``SIM_DEVICES=N`` before process start, which forces
 ``--xla_force_host_platform_device_count``), the B axis is sharded
 across devices with ``jax.sharding`` — lanes never communicate, so the
@@ -100,7 +101,6 @@ from repro.sim.mechanisms import (DEFAULT_MECHS, MAX_PTE, specs_for,
 from repro.util import spans
 
 MECHS = DEFAULT_MECHS
-M = len(MECHS)
 
 #: scan-chunk length; traces are padded to a multiple of this so one
 #: compiled runner serves every trace length
@@ -348,7 +348,7 @@ _CLEARED_MISSES = 0
 def runner_cache_info():
     """Cache stats of the compiled-runner cache: ``misses`` counts the
     runners built this process — one per distinct (machine shape,
-    walk-fn tuple, chunk, batched) combination, monotone across
+    walk-fn tuple, chunk) combination, monotone across
     :func:`clear_runner_cache`.  The sweep engine and its tests use
     this to assert "one compile per shape bucket"."""
     info = _chunk_runner.cache_info()
@@ -367,14 +367,13 @@ def clear_runner_cache() -> None:
     _chunk_runner.cache_clear()
 
 
-def init_state(mach: MachineConfig, m: int = M, batch: int | None = None):
+def init_state(mach: MachineConfig, m: int, lanes: int):
     c = mach.num_cores
-    # batch=None: one simulation, tables (C, M, sets, ways).  batch=B:
-    # B independent sims, tables (B, C, M, sets, ways).  Either way every
+    # ``lanes`` independent sims, tables (lanes, C, M, sets, ways): every
     # vmap level maps axis 0 with axis-0 outputs, so no per-step
     # transpose (= full table copy) is ever materialized.  Public results
     # stay (M, C) per sim.
-    lead = () if batch is None else (batch,)
+    lead = (lanes,)
 
     def table(sets, ways):
         return {"tags": jnp.zeros(lead + (c, m, sets, ways), jnp.int32),
@@ -415,13 +414,44 @@ def _line_scope(site: int) -> str:
     return "walk" if site < MAX_PTE else "data"
 
 
-def _build_model(shape: MachineShape, batched: bool = False):
+def lru_access(tab, num_sets: int, key, en, stamp, *, set_override=None):
+    """One scalar lookup+fill of a set-associative LRU table, the only
+    one the model has (caches, TLBs, PWCs, cache-as-TLB).
+
+    ``tab`` is ``{"tags": (sets, ways), "lru": (sets, ways)}`` int32 as
+    :func:`init_state` builds it (stored tag = ``key // sets + 1``, 0 =
+    invalid; ``lru`` = the stamp of the way's last use).  An enabled
+    access (``en``) hits where the set holds the tag and always fills:
+    the matching way on a hit, else the least recently stamped way, is
+    written with the tag and ``stamp``.  A disabled access reports a
+    miss and leaves the table as it was.  ``set_override`` pins the set
+    index (the PWC's set is the walk level) and stores ``key + 1``.
+    Returns ``(table, hit)``.  Scalar set index keeps XLA on the
+    dynamic-slice fast path — this is the per-step hot loop."""
+    if set_override is None:
+        s = jax.lax.rem(key, num_sets)
+        tag = jax.lax.div(key, num_sets) + 1
+    else:
+        s = set_override                        # pwc: set = walk level
+        tag = key + 1
+    row_tags = tab["tags"][s]
+    row_lru = tab["lru"][s]
+    match = row_tags == tag
+    hit = match.any() & en
+    # a match wins the argmin outright; otherwise it picks true LRU
+    way = jnp.argmin(jnp.where(match, _INT_MIN, row_lru))
+    s_safe = jnp.where(en, s, num_sets)         # disabled -> dropped
+    new = {"tags": tab["tags"].at[s_safe, way].set(tag, mode="drop"),
+           "lru": tab["lru"].at[s_safe, way].set(stamp, mode="drop")}
+    return new, hit
+
+
+def _build_model(shape: MachineShape):
     """The model, parameterized ONLY by shape: every latency and every
     per-mechanism flag arrives at trace time as an operand (``dp`` data
     params / ``mt`` mechanism tables), so one build serves a whole
-    sensitivity grid.  In the batched engine both may carry a leading
-    lane axis — lanes of one dispatch can simulate different machines
-    and mechanism variants."""
+    sensitivity grid.  Both carry a leading lane axis — lanes of one
+    dispatch can simulate different machines and mechanism variants."""
     hier = shape.hier
     shapes = _shape_tables(shape)
     has_ctlb = "ctlb" in shapes
@@ -448,32 +478,11 @@ def _build_model(shape: MachineShape, batched: bool = False):
     n_slots = 2 + MAX_PTE + 5 * len(hier) + (1 if has_ctlb else 0)
     ctlb_slot = 2 + MAX_PTE + 5 * len(hier)
 
-    def access(tab, sets, key, en, stamp, *, set_override=None):
-        """One scalar LRU lookup+fill.  Scalar set index keeps XLA on the
-        dynamic-slice fast path — this is the per-step hot loop."""
-        num_sets, _ = sets
-        if set_override is None:
-            s = jax.lax.rem(key, num_sets)
-            tag = jax.lax.div(key, num_sets) + 1
-        else:
-            s = set_override                        # pwc: set = walk level
-            tag = key + 1
-        row_tags = tab["tags"][s]
-        row_lru = tab["lru"][s]
-        match = row_tags == tag
-        hit = match.any() & en
-        # a match wins the argmin outright; otherwise it picks true LRU
-        way = jnp.argmin(jnp.where(match, _INT_MIN, row_lru))
-        s_safe = jnp.where(en, s, num_sets)         # disabled -> dropped
-        new = {"tags": tab["tags"].at[s_safe, way].set(tag, mode="drop"),
-               "lru": tab["lru"].at[s_safe, way].set(stamp, mode="drop")}
-        return new, hit
-
     def per_mc(sub, stamp, vpn, off, pte_lines, is4k, valid, mt):
         """Hit extraction for one (mech, core): touches every table once
         per gated access site, returns the packed hit bits.  ``mt`` is
-        this mechanism's scalar flag/depth values (vmapped off the M —
-        and, batched, the lane — axis of the mechanism tables)."""
+        this mechanism's scalar flag/depth values (vmapped off the lane
+        and M axes of the mechanism tables)."""
         ideal = mt["ideal"]
         huge = mt["huge"]
         byp = mt["bypass"]
@@ -487,19 +496,22 @@ def _build_model(shape: MachineShape, batched: bool = False):
             # fragmentation-broken rest enters the translation
             # machinery below
             en0 = valid & ~ideal & ~(mt["segment"] & ~is4k)
-            sub["l1tlb"], h_l1tlb = access(sub["l1tlb"], shapes["l1tlb"],
-                                           tlb_key, en0, stamp)
+            sub["l1tlb"], h_l1tlb = lru_access(sub["l1tlb"],
+                                               shapes["l1tlb"][0],
+                                               tlb_key, en0, stamp)
             en1 = en0 & ~h_l1tlb
-            sub["l2tlb"], h_l2tlb = access(sub["l2tlb"], shapes["l2tlb"],
-                                           tlb_key, en1, stamp + 1)
+            sub["l2tlb"], h_l2tlb = lru_access(sub["l2tlb"],
+                                               shapes["l2tlb"][0],
+                                               tlb_key, en1, stamp + 1)
             walk = en1 & ~h_l2tlb
             if has_ctlb:
                 # cache-as-TLB probe after an L2-TLB miss: a hit short-
                 # circuits the walk for cache_tlb mechanisms
                 en_ct = walk & mt["cache_tlb"]
-                sub["ctlb"], h_ctlb = access(sub["ctlb"], shapes["ctlb"],
-                                             tlb_key, en_ct,
-                                             stamp + ctlb_slot)
+                sub["ctlb"], h_ctlb = lru_access(sub["ctlb"],
+                                                 shapes["ctlb"][0],
+                                                 tlb_key, en_ct,
+                                                 stamp + ctlb_slot)
                 walk = walk & ~h_ctlb
 
         with jax.named_scope("walk"):
@@ -509,9 +521,10 @@ def _build_model(shape: MachineShape, batched: bool = False):
             pwc_hits = []
             for lvl in range(MAX_PTE):
                 en = walk & (lvl < eff_n) & mt["pwc_on"][lvl]
-                sub["pwc"], h = access(sub["pwc"], shapes["pwc"],
-                                       pte_lines[lvl], en, stamp + 2 + lvl,
-                                       set_override=lvl)
+                sub["pwc"], h = lru_access(sub["pwc"], shapes["pwc"][0],
+                                           pte_lines[lvl], en,
+                                           stamp + 2 + lvl,
+                                           set_override=lvl)
                 pwc_hits.append(h)
                 bits.append(h)
 
@@ -525,8 +538,8 @@ def _build_model(shape: MachineShape, batched: bool = False):
             nxt = []
             for i in range(5):
                 with jax.named_scope(_line_scope(i)):
-                    sub[name], h = access(sub[name], shapes[name],
-                                          lines[i], ens[i], slot + i)
+                    sub[name], h = lru_access(sub[name], shapes[name][0],
+                                              lines[i], ens[i], slot + i)
                 nxt.append(ens[i] & ~h)
                 bits.append(h)
             ens = nxt
@@ -557,44 +570,39 @@ def _build_model(shape: MachineShape, batched: bool = False):
                   * (1 << jnp.arange(n_bits, dtype=jnp.int32))).sum()
         return sub, stamp + n_slots, packed
 
-    # inner vmap over mechanisms, outer over cores — every mapped input
+    # inner vmap over mechanisms, outer over lanes — every mapped input
     # and output uses axis 0 so XLA never transposes the carried tables.
-    # The batched variant serves the B (independent-simulation) axis
-    # FUSED into the core axis: lanes are fully independent either way,
-    # and a wider leading axis is the layout XLA-CPU already handles
-    # well, whereas a literal third vmap level regresses the per-step
-    # gathers.  ``valid`` and the mechanism tables change: per-sim trace
-    # lengths and per-sim mechanism values make them per-lane inputs.
+    # A lane is one core of one simulation: the B (independent-
+    # simulation) axis is FUSED into the core axis, the layout XLA-CPU
+    # handles well, whereas a literal third vmap level regresses the
+    # per-step gathers.  ``valid`` and the mechanism tables are per-lane
+    # inputs: per-sim trace lengths and per-sim mechanism values.
     per_core = jax.vmap(per_mc,
                         in_axes=(0, 0, None, None, 0, None, None, 0))
-    full = jax.vmap(per_core,
-                    in_axes=(0, 0, 0, 0, 0, 0, None, None))
-    full_v = jax.vmap(per_core,
-                      in_axes=(0, 0, 0, 0, 0, 0, 0, 0))
+    all_lanes = jax.vmap(per_core,
+                         in_axes=(0, 0, 0, 0, 0, 0, 0, 0))
 
     def make_step(mt):
         def step(carry, x):
             sub, stamp = carry
             vpn, off, pte_lines, is4k, valid = x
-            fn = full_v if batched else full
-            sub, stamp, packed = fn(sub, stamp, vpn, off, pte_lines,
-                                    is4k, valid, mt)
+            sub, stamp, packed = all_lanes(sub, stamp, vpn, off,
+                                           pte_lines, is4k, valid, mt)
             return (sub, stamp), packed
         return step
 
     def epilogue(packed, work, is4k, valid, q, mt, dp, lines=None):
         """Vectorized timing over the whole chunk.
 
-        packed: (T, M, C) hit bits; work/is4k: (T, C); valid: (T,) — or
-        (T, C) per-lane in the batched engine, where C is the fused
-        B*cores axis; q: (M,) queue delay — (M, C) when batched (per-sim
-        windows expanded per lane) — constant within the chunk.  Banked
-        memory generalizes q to a trailing bank axis ((M, banks) /
-        (M, C, banks)) and passes ``lines`` (T, M, C, 5), the line ids
-        of the five access sites, so each access gathers ITS bank's
-        queue window and row-hit discount.
-        ``mt`` mechanism tables ((M,) leaves, or (C, M) per lane) and
-        ``dp`` data params (scalars, or (C,) per lane) are operands.
+        packed: (T, M, C) hit bits, where C is the fused lanes axis
+        (B*cores); work/is4k/valid: (T, C); q: (M, C) queue delay (per-sim
+        windows expanded per lane), constant within the chunk.  Banked
+        memory generalizes q to a trailing bank axis (M, C, banks) and
+        passes ``lines`` (T, M, C, 5), the line ids of the five access
+        sites, so each access gathers ITS bank's queue window and
+        row-hit discount.
+        ``mt`` mechanism tables ((C, M) leaves) and ``dp`` data params
+        ((C,) leaves) are operands.
         Re-derives the same gates the scan used (pure functions of the
         hit bits) and produces the (M, C) counter/clock deltas.
         """
@@ -602,16 +610,15 @@ def _build_model(shape: MachineShape, batched: bool = False):
             return ((packed >> i) & 1).astype(bool)
 
         def mb(a):          # mech table -> broadcast over (T, M, C)
-            return a[None, :, None] if a.ndim == 1 else a.T[None]
+            return a.T[None]
 
         def d3(v):          # data param -> broadcast over (T, M, C)
-            return v if v.ndim == 0 else v[None, None, :]
+            return v[None, None, :]
 
         def d4(v):          # data param -> broadcast over (T, M, C, 5)
-            return v if v.ndim == 0 else v[None, None, :, None]
+            return v[None, None, :, None]
 
-        validb = (valid[:, None, None] if valid.ndim == 1
-                  else valid[:, None, :])                   # (T, 1, 1|C)
+        validb = valid[:, None, :]                          # (T, 1, C)
         is4kb = is4k[:, None, :]                            # (T, 1, C)
         idealb = mb(mt["ideal"])
         hugeb = mb(mt["huge"])
@@ -635,14 +642,13 @@ def _build_model(shape: MachineShape, batched: bool = False):
         if has_banked:
             rowhit = jnp.stack([bit(bank_bit + i) for i in range(5)], -1)
             bank5 = (lines // lines_per_row) % n_banks     # (T, M, C, 5)
-            qfull = q[None, :, None, :] if q.ndim == 2 else q[None]
             q_acc = jnp.take_along_axis(
-                jnp.broadcast_to(qfull, packed.shape + (n_banks,)),
+                jnp.broadcast_to(q[None], packed.shape + (n_banks,)),
                 bank5, axis=-1)                            # (T, M, C, 5)
             mem_cost = (mem4 - rowhit * d4(dp["row_save"])
                         + q_acc + pen4)
         else:
-            qb = q[None, :, None] if q.ndim == 1 else q[None]  # (1,M,1|C)
+            qb = q[None]                                    # (1, M, C)
             mem_cost = mem4 + qb[..., None] + pen4
 
         h_l1tlb, h_l2tlb = bit(0), bit(1)
@@ -709,10 +715,10 @@ def _build_model(shape: MachineShape, batched: bool = False):
 
         # NB: XLA-CPU's axis-0 reduce keeps one association for every
         # lane width except 1 (rank-collapse special case), so these f32
-        # sums are bit-stable between batch and single dispatch as long
-        # as the lane minor-dim stays >= 2 — which simulate/simulate_batch
-        # guarantee by padding 1-lane runs (integer-valued counters are
-        # order-exact regardless).  tests/test_batch.py pins this.
+        # sums are bit-stable across batch sizes as long as the lane
+        # minor-dim stays >= 2 — which _prepare guarantees by padding
+        # 1-lane runs (integer-valued counters are order-exact
+        # regardless).  tests/test_batch.py pins this.
         f32 = lambda a: a.astype(jnp.float32).sum(axis=0)   # noqa: E731
         cnt = {
             "trans": trans.sum(axis=0),
@@ -744,23 +750,20 @@ def _build_model(shape: MachineShape, batched: bool = False):
 # chunked driver
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
-def _chunk_runner(shape: MachineShape, walk_fns: Tuple, chunk: int,
-                  batch: bool = False):
-    """One jitted (scan + epilogue) over a chunk, specialized per
-    (machine SHAPE, walk-fn tuple, chunk length) and cached for the life
-    of the process.  Machine latencies (``dp``) and per-mechanism flag
-    tables (``mt``) are operands, so every value-only machine or
-    mechanism variant reuses the same compiled runner.  State buffers
-    are donated: chunk i+1 reuses chunk i's memory.  The per-mechanism
-    PTE walk lines are derived from the VPNs inside the jit so the host
-    never materializes (T, C, M, MAX_PTE).
-
-    ``batch=True`` builds the B-axis variant: xs arrive as (T, B, C)
-    (valid: (T, B)), state carries a leading B, mt/dp carry a leading B
-    (heterogeneous lanes), and the queue window is tracked per sim.
-    One jitted callable serves every B (jit re-traces per shape) and
-    every sharding of the B axis."""
-    make_step, epilogue = _build_model(shape, batched=batch)
+def _chunk_runner(shape: MachineShape, walk_fns: Tuple, chunk: int):
+    """One jitted (scan + epilogue) over a chunk of B simulations,
+    specialized per (machine SHAPE, walk-fn tuple, chunk length) and
+    cached for the life of the process.  xs arrive as (T, B, C) (valid:
+    (T, B)), state carries a leading B, and the queue window is tracked
+    per sim.  Machine latencies (``dp``) and per-mechanism flag tables
+    (``mt``) are operands with a leading B (heterogeneous lanes), so
+    every value-only machine or mechanism variant reuses the same
+    compiled runner.  State buffers are donated: chunk i+1 reuses chunk
+    i's memory.  The per-mechanism PTE walk lines are derived from the
+    VPNs inside the jit so the host never materializes (T, C, M,
+    MAX_PTE).  One jitted callable serves every B (jit re-traces per
+    shape) and every sharding of the B axis."""
+    make_step, epilogue = _build_model(shape)
     table_names = tuple(n for n, _, _ in shape.tables)
     has_banked = shape.memory[0] == "banked"
     # banked memory: per-bank open-row ids join the scan carry, and the
@@ -768,9 +771,9 @@ def _chunk_runner(shape: MachineShape, walk_fns: Tuple, chunk: int,
     carry_names = table_names + (("bank_row",) if has_banked else ())
 
     def walk_lines(vpn, is4k, huge):
-        """(..., C) vpns -> (..., C, M, MAX_PTE) PTE line ids.  ``huge``
-        is runtime data ((M,) or (lanes, M)): huge-page mechanisms blend
-        in the radix fallback lines for fragmented (4KB) regions."""
+        """(T, C) vpns -> (T, C, M, MAX_PTE) PTE line ids.  ``huge`` is
+        runtime data (C, M): huge-page mechanisms blend in the radix
+        fallback lines for fragmented (4KB) regions."""
         radix = _pad_lines(PT.radix4_walk_lines(vpn))
         per_mech = []
         for i, fn in enumerate(walk_fns):
@@ -780,8 +783,8 @@ def _chunk_runner(shape: MachineShape, walk_fns: Tuple, chunk: int,
                 lines = radix
             else:
                 lines = _pad_lines(fn(vpn))
-            h = huge[i] if huge.ndim == 1 else huge[None, :, i, None]
-            lines = jnp.where(h & is4k[..., None], radix, lines)
+            lines = jnp.where(huge[None, :, i, None] & is4k[..., None],
+                              radix, lines)
             per_mech.append(lines)
         return jnp.stack(per_mech, axis=-2)
 
@@ -796,13 +799,11 @@ def _chunk_runner(shape: MachineShape, walk_fns: Tuple, chunk: int,
         elapsed = jnp.maximum(clock.mean(axis=-1), 1.0)
         if has_banked:
             rate = mem_accs / elapsed[..., None]
-            svc = (service if service.ndim == 0
-                   else service[:, None, None])
-            return MM.queue_delay(rate, svc)  # (M, bk) / batched (B, M, bk)
+            return MM.queue_delay(rate, service[:, None, None])  # (B,M,bk)
         rate = mem_accs / elapsed                 # aggregate accesses/cycle
-        svc = service if service.ndim == 0 else service[:, None]
+        svc = service[:, None]
         rho = jnp.clip(rate * svc, 0.0, 0.96)
-        return svc * rho * QUEUE_K                # (M,) / batched (B, M)
+        return svc * rho * QUEUE_K                # (B, M)
 
     def _lines5(pte, vpn, off):
         # the five access sites' line ids in epilogue orientation
@@ -812,34 +813,14 @@ def _chunk_runner(shape: MachineShape, walk_fns: Tuple, chunk: int,
         return jnp.concatenate(
             [pm, jnp.broadcast_to(dl, pm.shape[:-1] + (1,))], -1)
 
-    def run(state, xs, mt, dp):
-        vpn, off, work, is4k, valid = xs
-        pte = walk_lines(vpn, is4k, mt["huge"])
-        q = _queue(state["clock"], state["mem_accs"], dp["service"])
-        carry = ({k: state[k] for k in carry_names}, state["stamp"])
-        (tabs, stamp), packed = jax.lax.scan(
-            make_step(mt), carry, (vpn, off, pte, is4k, valid))
-        # scan emits (T, C, M); the cheap summary arrays go back to the
-        # public (T, M, C) orientation here
-        cnt, cyc, mem_n = epilogue(
-            jnp.swapaxes(packed, 1, 2), work, is4k, valid, q, mt, dp,
-            lines=_lines5(pte, vpn, off) if has_banked else None)
-
-        new_state = dict(tabs)
-        new_state["stamp"] = stamp
-        new_state["clock"] = state["clock"] + cyc
-        new_state["mem_accs"] = state["mem_accs"] + mem_n.sum(axis=1)
-        new_state["counters"] = {
-            k: state["counters"][k] + cnt[k] for k in state["counters"]}
-        return new_state
-
     def run_batch(state, xs, mt, dp):
         """B sims as one dispatch.  State arrives (B, C, M, ...) and is
         reshaped — free, the leading axes are contiguous — onto the
-        fused (B*C, M, ...) lane layout the proven two-level engine
-        runs; valid bits, queue windows, mechanism tables, and data
-        params are expanded per lane.  Public counters stay per-sim
-        (B, M, C)."""
+        fused (B*C, M, ...) lane layout the two vmap levels run; valid
+        bits, queue windows, mechanism tables, and data params are
+        expanded per lane.  Public counters stay per-sim (B, M, C).
+        Its name is the jit's, ``jit(run_batch)``, which opens the
+        op_name of every instruction a device trace shows."""
         vpn, off, work, is4k, valid = xs          # (T, B, C); valid (T, B)
         t, b, c = vpn.shape
         m = state["stamp"].shape[-1]
@@ -881,7 +862,7 @@ def _chunk_runner(shape: MachineShape, walk_fns: Tuple, chunk: int,
             for k in state["counters"]}
         return new_state
 
-    return jax.jit(run_batch if batch else run, donate_argnums=(0,))
+    return jax.jit(run_batch, donate_argnums=(0,))
 
 
 # a spec re-registered with overwrite=True must not keep serving runners
@@ -908,80 +889,14 @@ def simulate(mach: MachineConfig, trace: Dict[str, np.ndarray] | str,
     """Run the registered mechanisms over a multi-core trace on ``mach``.
 
     ``mechs`` selects/orders mechanisms from the spec registry (default:
-    the paper's five).  The trace is zero-padded to a multiple of
+    the paper's five).  The run is a batch of one on one device
+    (:func:`simulate_batch`): the trace is zero-padded to a multiple of
     ``chunk`` (padding is masked out of every counter) and streamed
     through the cached chunk runner.  ``trace`` may be a
     ``"trace:<path>"`` spec for an ingested real trace.
     """
-    names = DEFAULT_MECHS if mechs is None else tuple(mechs)
-    trace = _resolve_trace(trace, mach.num_cores, length)
-
-    if mach.num_cores == 1:
-        # run 1-core sims on the batch engine (padded to 2 lanes there):
-        # a single lane would hit XLA's width-1 reduce special case,
-        # whose float accumulation order differs from every width >= 2 —
-        # breaking batch-vs-single bit-exactness
-        return simulate_batch(mach, [trace], length, mechs=names,
-                              chunk=chunk, devices=1)[0]
-    return _simulate_single(mach, trace, length, names, chunk)
-
-
-def _simulate_single(mach: MachineConfig, trace: Dict[str, np.ndarray],
-                     length: int | None, names: Tuple[str, ...],
-                     chunk: int) -> SimResult:
-    """The non-batched engine — every core count runs here via
-    :func:`simulate` except C=1 (rerouted, see above).  The batch tests
-    also drive this directly as an independent oracle (to float
-    tolerance at C=1, where the rerouting makes exactness impossible).
-    """
-    m = len(specs_for(names))
-    vpn = trace["vpn"][:, :length] if length else trace["vpn"]
-    off = trace["off"][:, : vpn.shape[1]]
-    work = trace["work"][:, : vpn.shape[1]]
-    c, t = vpn.shape
-    assert c == mach.num_cores, (c, mach.num_cores)
-
-    # huge-page fragmentation: which 2MB regions fell back to 4KB mappings
-    frac = FRAC_4K.get(mach.num_cores, min(0.93, 0.05 + 0.11 *
-                                           mach.num_cores))
-    region = vpn >> HUGE_SHIFT
-    is4k_np = (_hash_np(region) % 1000) < int(frac * 1000)
-
-    pad = (-t) % chunk
-    pad_np = lambda a: np.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))  # noqa: E731
-    valid = np.arange(t + pad) < t
-    xs = (pad_np(np.ascontiguousarray(vpn.T, np.int32)),
-          pad_np(np.ascontiguousarray(off.T, np.int32)),
-          pad_np(np.ascontiguousarray(work.T, np.float32)),
-          pad_np(np.ascontiguousarray(is4k_np.T)),
-          valid)
-    xs = tuple(jnp.asarray(a) for a in xs)
-
-    runner = _chunk_runner(machine_shape(mach), _walk_fns(names), chunk)
-    mt = {k: jnp.asarray(v) for k, v in _mech_arrays(names).items()}
-    dp = {k: jnp.asarray(v) for k, v in _data_params(mach).items()}
-    state = init_state(mach, m)
-    for i in range(0, t + pad, chunk):
-        state = runner(state, jax.tree.map(lambda a: a[i:i + chunk], xs),
-                       mt, dp)
-    state = jax.block_until_ready(state)
-
-    cnt = {k: np.asarray(v) for k, v in state["counters"].items()}
-    return SimResult(
-        mechs=names,
-        cycles=np.asarray(state["clock"]),
-        instructions=np.asarray((work + 1).sum(axis=1), np.float64),
-        trans_cycles=cnt["trans"],
-        walk_cycles=cnt["walk_cyc"],
-        walks=cnt["walks"],
-        l1tlb_misses=cnt["l1tlb_miss"],
-        accesses=t,
-        pte_accesses=cnt["pte_acc"],
-        pte_l1_hits=cnt["pte_l1_hit"],
-        pte_mem=cnt["pte_mem"],
-        data_l1_misses=cnt["data_l1_miss"],
-        data_mem=cnt["data_mem"],
-    )
+    return simulate_batch(mach, [trace], length, mechs=mechs, chunk=chunk,
+                          devices=1)[0]
 
 
 def simulate_batch(mach: MachineConfig,
@@ -991,7 +906,7 @@ def simulate_batch(mach: MachineConfig,
                    chunk: int = DEFAULT_CHUNK,
                    devices: int | None = None,
                    timings: Dict | None = None) -> List[SimResult]:
-    """Run B independent simulations sharing ``mach`` as ONE batched
+    """Run B independent simulations sharing ``mach`` as ONE
     chunked-scan dispatch.
 
     ``traces`` is a sequence of trace dicts (each ``(num_cores, T_i)``)
@@ -1037,7 +952,7 @@ def simulate_batch_varied(jobs: Sequence[SimJob],
                           chunk: int = DEFAULT_CHUNK,
                           devices: int | None = None,
                           timings: Dict | None = None) -> List[SimResult]:
-    """B heterogeneous (machine, trace, mechanisms) jobs as ONE batched
+    """B heterogeneous (machine, trace, mechanisms) jobs as ONE
     chunked-scan dispatch — the sweep engine's bucket primitive.
 
     The jobs must form one *shape bucket*: equal :func:`machine_shape`
@@ -1207,7 +1122,7 @@ def _prepare(jobs: Sequence[SimJob], length: int | None, chunk: int,
         xs = tuple(jnp.asarray(a) for a in xs)
         mt = {k: jnp.asarray(v) for k, v in mt.items()}
         dp = {k: jnp.asarray(v) for k, v in dp.items()}
-        state = init_state(jobs[0].mach, m, batch=bp)
+        state = init_state(jobs[0].mach, m, bp)
         if ndev > 1:
             mesh = Mesh(np.asarray(jax.devices()[:ndev]), ("b",))
             st_sh = NamedSharding(mesh, P("b"))   # state: B leading
@@ -1221,7 +1136,7 @@ def _prepare(jobs: Sequence[SimJob], length: int | None, chunk: int,
     if timed:
         timings["pack_s"] = sp_pack.seconds
         timings["transfer_s"] = sp_xfer.seconds
-    return _Prepared(jobs, _chunk_runner(shape, wf, chunk, batch=True),
+    return _Prepared(jobs, _chunk_runner(shape, wf, chunk),
                      state, chunks, mt, dp, lens, works)
 
 

@@ -1,6 +1,9 @@
-"""simulate_batch: bit-exactness vs per-sim simulate, B-axis sharding,
-and SimResult slicing helpers."""
+"""simulate_batch: lanes independent of their neighbours, agreement
+with the plain numpy reference, B-axis sharding, and SimResult slicing
+helpers."""
 import dataclasses
+import importlib.util
+import json
 import os
 import subprocess
 import sys
@@ -11,8 +14,12 @@ import pytest
 
 from repro.configs.ndp_sim import cpu_machine, ndp_machine
 from repro.sim import simulate, simulate_batch
+from repro.sim import simulator as S
 from repro.sim.mechanisms import DEFAULT_MECHS
 from repro.workloads import generate_traces
+
+BENCH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                     "bench")
 
 WORKLOADS3 = ("rnd", "bc", "bfs")
 LEN = 700          # spans a chunk boundary at chunk=512
@@ -29,9 +36,20 @@ def _assert_results_equal(a, b, msg=""):
             assert va == vb, f"{msg}: {f.name}"
 
 
+def _sim_ref():
+    """The benchmark's plain numpy reference of the timing model."""
+    spec = importlib.util.spec_from_file_location(
+        "sim_ref", os.path.join(BENCH, "reference", "sim_ref.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 class TestBitExact:
-    """The batch engine must reproduce per-sim simulate() exactly —
-    every counter and cycle, not just within tolerance."""
+    """A lane's result must not depend on its neighbours or on its
+    position in the batch: simulate() (a batch of one) and one lane of
+    simulate_batch agree exactly — every counter and cycle, not just
+    within tolerance."""
 
     @pytest.mark.parametrize("cores", [1, 2])
     def test_batch_equals_loop_ndp(self, cores):
@@ -45,7 +63,7 @@ class TestBitExact:
 
     def test_batch_equals_loop_cpu_with_pl3(self):
         # the CPU hierarchy (L2+L3) and a registered extension mechanism
-        # both ride the same batched lanes
+        # keep a lane independent of its neighbours too
         mach = cpu_machine(2)
         names = DEFAULT_MECHS + ("ndpage_pl3",)
         traces = generate_traces(WORKLOADS3[:2], 2, length=LEN, seed=7)
@@ -57,7 +75,8 @@ class TestBitExact:
             _assert_results_equal(s, b, msg="cpu2+pl3")
 
     def test_mixed_trace_lengths(self):
-        # lanes with different true lengths are masked per-sim
+        # lanes with different true lengths are masked per-sim: a short
+        # lane beside a long one equals its own batch of one
         mach = ndp_machine(1)
         t_long = generate_traces(("rnd",), 1, length=LEN, seed=7)[0]
         t_short = generate_traces(("bc",), 1, length=300, seed=7)[0]
@@ -72,28 +91,36 @@ class TestBitExact:
     def test_empty_batch(self):
         assert simulate_batch(ndp_machine(1), []) == []
 
-    def test_single_core_vs_nonbatched_oracle(self):
-        """At 1 core, simulate() reroutes through the batch engine (the
-        non-batched width-1 lane reduce reassociates), so the looped-vs-
-        batched test above compares the batch engine to itself there.
-        This pins the rerouted result against the ORIGINAL non-batched
-        engine: integer event counters must match exactly, float cycle
-        accumulators to reduction-order tolerance."""
-        from repro.sim import simulator as S
-        mach = ndp_machine(1)
-        tr = generate_traces(("rnd",), 1, length=LEN, seed=7)[0]
-        batched = simulate_batch(mach, [tr], chunk=512)[0]
-        oracle = S._simulate_single(mach, tr, None, DEFAULT_MECHS, 512)
-        float_accum = {"cycles", "trans_cycles", "walk_cycles"}
-        for f in dataclasses.fields(oracle):
-            va, vb = getattr(oracle, f.name), getattr(batched, f.name)
-            if f.name in float_accum:
-                np.testing.assert_allclose(va, vb, rtol=1e-6,
-                                           err_msg=f.name)
-            elif isinstance(va, np.ndarray):
-                np.testing.assert_array_equal(va, vb, err_msg=f.name)
-            else:
-                assert va == vb, f.name
+
+@pytest.mark.parametrize("cores", [1, 2])
+def test_simulate_matches_numpy_reference(cores):
+    """The one engine against the benchmark's plain numpy reference
+    (``bench/reference/sim_ref.py``, none of the simulator's code) on
+    the paper's NDP machine: integer counters exactly, the cycle results
+    to CYCLES_RTOL.  The reference takes the machine and mechanisms from
+    the ndp-8c configuration at this core count, with the simulator's
+    calibration for it (huge-page fragmentation and stall, ECH rehash)."""
+    ref = _sim_ref()
+    with open(os.path.join(BENCH, "configs", "ndp-8c.json")) as f:
+        cfg = json.load(f)
+    machine = dict(
+        cfg["machine"], num_cores=cores,
+        fragmented_4k_share=S.FRAC_4K[cores],
+        hugepage_stall_cycles=S.HP_STALL_BASE
+        + S.HP_STALL_PER_CORE * (cores - 1),
+        ech_rehash_cycles=S.ECH_REHASH_QUAD * max(cores - 2, 0) ** 2)
+    traces = generate_traces(WORKLOADS3, cores, length=LEN, seed=7)
+    got = simulate_batch(ndp_machine(cores), traces, chunk=512)
+    want = ref.simulate(machine, cfg["mechanisms"], traces, 512)
+    assert len(got) == len(want) == len(traces)
+    for w, g, r in zip(WORKLOADS3, got, want):
+        for k in ref.COUNTERS:
+            np.testing.assert_array_equal(getattr(g, k), r[k],
+                                          err_msg=f"{w}: {k}")
+        for k in ref.CYCLES:
+            np.testing.assert_allclose(getattr(g, k), r[k],
+                                       rtol=S.CYCLES_RTOL,
+                                       err_msg=f"{w}: {k}")
 
 
 class TestSharding:

@@ -12,24 +12,29 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 from repro.core import block_table as BT
 from repro.core.kv_page_manager import KVPageManager
 from repro.kernels import ref
-from repro.sim import cache_model as CM
+from repro.sim.simulator import lru_access
 
 SET = settings(max_examples=25, deadline=None)
 
 
 # ---------------------------------------------------------------------------
-# cache model: inclusion & capacity invariants
+# LRU lookup: inclusion & capacity invariants
 # ---------------------------------------------------------------------------
+def _table(sets_, ways):
+    return {"tags": jnp.zeros((sets_, ways), jnp.int32),
+            "lru": jnp.zeros((sets_, ways), jnp.int32)}
+
+
 @SET
 @given(keys=st.lists(st.integers(0, 63), min_size=1, max_size=60),
        sets_=st.sampled_from([1, 2, 4]), ways=st.sampled_from([1, 2, 4]))
 def test_cache_hit_implies_previously_inserted(keys, sets_, ways):
-    state = CM.make(sets_, ways)
+    state = _table(sets_, ways)
     seen = set()
     t = jnp.asarray(True)
-    for k in keys:
-        state, hit = CM.access(state, jnp.asarray(k, jnp.int32),
-                               insert=t, enabled=t)
+    for i, k in enumerate(keys):
+        state, hit = lru_access(state, sets_, jnp.asarray(k, jnp.int32), t,
+                                jnp.asarray(i + 1, jnp.int32))
         if bool(hit):
             assert k in seen
         seen.add(k)
@@ -39,11 +44,11 @@ def test_cache_hit_implies_previously_inserted(keys, sets_, ways):
 @given(keys=st.lists(st.integers(0, 1000), min_size=1, max_size=50))
 def test_cache_never_exceeds_capacity(keys):
     sets_, ways = 2, 2
-    state = CM.make(sets_, ways)
+    state = _table(sets_, ways)
     t = jnp.asarray(True)
-    for k in keys:
-        state, _ = CM.access(state, jnp.asarray(k, jnp.int32),
-                             insert=t, enabled=t)
+    for i, k in enumerate(keys):
+        state, _ = lru_access(state, sets_, jnp.asarray(k, jnp.int32), t,
+                              jnp.asarray(i + 1, jnp.int32))
     assert int((state["tags"] > 0).sum()) <= sets_ * ways
 
 

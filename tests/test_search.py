@@ -3,8 +3,8 @@ the 1-candidate == sweep() bit-exactness bridge, the compile-count
 bound over a full generation, and the BENCH/baseline plumbing.
 
 Chunk lengths 416/448 are unique to this file so runner-cache compile
-accounting is exact (the cache is keyed on (shape, walk fns, chunk,
-batched) and shared process-wide).
+accounting is exact (the cache is keyed on (shape, walk fns, chunk)
+and shared process-wide).
 """
 import itertools
 import json

@@ -1,11 +1,11 @@
-"""Simulator unit + behaviour tests (cache model, mechanisms ordering)."""
+"""Simulator unit + behaviour tests (LRU lookup, mechanisms ordering)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.ndp_sim import cpu_machine, ndp_machine
-from repro.sim import cache_model as CM
 from repro.sim import simulate
+from repro.sim.simulator import lru_access
 from repro.workloads import generate_trace
 
 T = jnp.asarray(True)
@@ -16,36 +16,53 @@ def key(x):
     return jnp.asarray(x, jnp.int32)
 
 
+def table(sets, ways):
+    """One (mech, core)'s LRU table as init_state lays it out."""
+    return {"tags": jnp.zeros((sets, ways), jnp.int32),
+            "lru": jnp.zeros((sets, ways), jnp.int32)}
+
+
+def run(tab, keys, en=T, stamp=1):
+    """Access ``keys`` in order with rising stamps; returns the table,
+    the hit bits and the next stamp."""
+    sets = tab["tags"].shape[0]
+    hits = []
+    for k in keys:
+        tab, hit = lru_access(tab, sets, key(k), en, key(stamp))
+        hits.append(bool(hit))
+        stamp += 1
+    return tab, hits, stamp
+
+
 class TestCacheModel:
+    """The scan's one LRU lookup, :func:`lru_access`: an enabled access
+    always fills, so a probe is an access too."""
+
     def test_miss_then_hit(self):
-        st = CM.make(4, 2)
-        st, hit = CM.access(st, key(5), insert=T, enabled=T)
-        assert not bool(hit)
-        st, hit = CM.access(st, key(5), insert=T, enabled=T)
-        assert bool(hit)
+        _, hits, _ = run(table(4, 2), (5, 5))
+        assert hits == [False, True]
 
     def test_lru_eviction(self):
-        st = CM.make(1, 2)  # fully assoc, 2 ways
-        for k in (1, 2):
-            st, _ = CM.access(st, key(k), insert=T, enabled=T)
-        st, _ = CM.access(st, key(1), insert=T, enabled=T)   # 1 is MRU
-        st, _ = CM.access(st, key(3), insert=T, enabled=T)   # evicts 2
-        st, hit1 = CM.access(st, key(1), insert=F, enabled=T)
-        st, hit2 = CM.access(st, key(2), insert=F, enabled=T)
-        assert bool(hit1) and not bool(hit2)
+        st = table(1, 2)  # fully assoc, 2 ways
+        st, _, n = run(st, (1, 2))
+        st, _, n = run(st, (1,), stamp=n)     # 1 is MRU
+        st, _, n = run(st, (3,), stamp=n)     # evicts 2
+        # probing 1 refills it as MRU, so 1 is probed before 2
+        _, (hit1, hit2), _ = run(st, (1, 2), stamp=n)
+        assert hit1 and not hit2
 
     def test_disabled_access_is_invisible(self):
-        st = CM.make(4, 2)
-        st2, hit = CM.access(st, key(9), insert=T, enabled=F)
-        assert not bool(hit)
+        st = table(4, 2)
+        st2, hits, _ = run(st, (9,), en=F)
+        assert hits == [False]
         assert (st2["tags"] == st["tags"]).all()
+        assert (st2["lru"] == st["lru"]).all()
 
     def test_set_isolation(self):
-        st = CM.make(4, 1)
-        st, _ = CM.access(st, key(0), insert=T, enabled=T)   # set 0
-        st, _ = CM.access(st, key(1), insert=T, enabled=T)   # set 1
-        st, hit = CM.access(st, key(0), insert=F, enabled=T)
-        assert bool(hit)
+        st = table(4, 1)
+        st, _, n = run(st, (0, 1))            # set 0, then set 1
+        _, hits, _ = run(st, (0,), stamp=n)
+        assert hits == [True]
 
 
 class TestSimulator:

@@ -17,8 +17,8 @@ from repro.sim import apply_param, simulate, sweep
 from repro.workloads import generate_trace
 
 #: chunk lengths unique to this file so runner-cache accounting below is
-#: exact (the cache is keyed on (shape, walk fns, chunk, batched) and
-#: shared process-wide; a chunk no other test uses -> fresh keys)
+#: exact (the cache is keyed on (shape, walk fns, chunk) and shared
+#: process-wide; a chunk no other test uses -> fresh keys)
 CHUNK_A = 320
 CHUNK_B = 352
 LEN = 700

@@ -215,7 +215,7 @@ def test_sim_chunk_runner(one_chip, machine, cores):
     on = lambda tree: jax.tree.map(                          # noqa: E731
         lambda a: _spec(one_chip, a.shape, a.dtype), tree)
     state = on(jax.eval_shape(
-        lambda: S.init_state(mach, len(S.MECHS), batch=lanes)))
+        lambda: S.init_state(mach, len(S.MECHS), lanes)))
     tbc = (chunk, lanes, cores)
     xs = (_spec(one_chip, tbc, jnp.int32), _spec(one_chip, tbc, jnp.int32),
           _spec(one_chip, tbc, jnp.float32), _spec(one_chip, tbc, jnp.bool_),
@@ -225,6 +225,6 @@ def test_sim_chunk_runner(one_chip, machine, cores):
     dp = {k: _spec(one_chip, (lanes,), np.float32)
           for k in S._data_params(mach)}
     runner = S._chunk_runner(S.machine_shape(mach), S._walk_fns(S.MECHS),
-                             chunk, batch=True)
+                             chunk)
     mem = runner.lower(state, xs, mt, dp).compile().memory_analysis()
     assert mem.argument_size_in_bytes > 0
